@@ -41,6 +41,7 @@ import json
 import os
 from typing import Dict, List
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.fpm import mesh_over_devices, mine
 from repro.core.join_backend import SweepDispatcher, get_backend
 from repro.core.tidlist import BitmapArena, pack_database
@@ -391,4 +392,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 from typing import Dict, List
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.fpm import mine
 from repro.core.tidlist import pack_database
 from repro.data.transactions import PROFILES, load
@@ -57,4 +58,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -52,6 +52,7 @@ from typing import Dict, List
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.cluster import mine_cluster
 from repro.core.fpm import mine
 from repro.core.tidlist import pack_database
@@ -289,4 +290,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

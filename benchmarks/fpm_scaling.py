@@ -4,6 +4,7 @@ from __future__ import annotations
 
 from typing import Dict, List
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.fpm import mine, mine_serial
 from repro.core.tidlist import pack_database
 from repro.data.transactions import load
@@ -42,4 +43,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -57,6 +57,7 @@ from typing import Dict, Iterator, List, Tuple
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.fpm import mine
 from repro.core.streaming import PatternServer, StreamingMiner
 from repro.core.tidlist import pack_database
@@ -547,4 +548,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -28,6 +28,7 @@ import json
 import os
 from typing import Dict, List
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.fpm import mine
 from repro.core.streaming import PatternServer, StreamingMiner
 from repro.core.tidlist import pack_database
@@ -181,4 +182,5 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

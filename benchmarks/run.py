@@ -5,7 +5,7 @@
   fpm_granularity  bucket-sweep vs per-candidate tasks (smoke sizes)
   fpm_locality     Table 1 (locality metrics)
   fpm_scaling      worker scaling
-  fpm_distributed  clustered vs round-robin placement on an 8-dev mesh
+  fpm_multihost    multi-host capacity, steal migration, 8-dev mesh rows
   fpm_streaming    ingest / incremental-refresh / serving latencies
   kernels_bench    kernel micro-benches + analytic TPU bounds
 """
@@ -14,16 +14,17 @@ from __future__ import annotations
 import sys
 import traceback
 
-from benchmarks import (fpm_distributed, fpm_granularity, fpm_locality,
+from benchmarks import (fpm_granularity, fpm_locality, fpm_multihost,
                         fpm_policies, fpm_scaling, fpm_streaming,
                         kernels_bench)
+from repro.compile_cache import enable_compile_cache
 
 ALL = [
     ("fpm_policies", fpm_policies.main),
     ("fpm_granularity", lambda: fpm_granularity.main(["--smoke"])),
     ("fpm_locality", fpm_locality.main),
     ("fpm_scaling", fpm_scaling.main),
-    ("fpm_distributed", fpm_distributed.main),
+    ("fpm_multihost", lambda: fpm_multihost.main(["--smoke"])),
     ("fpm_streaming", lambda: fpm_streaming.main(["--smoke"])),
     ("kernels_bench", kernels_bench.main),
 ]
@@ -48,4 +49,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

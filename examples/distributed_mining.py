@@ -15,11 +15,13 @@ import sys; sys.path.insert(0, "src")
 import time
 import jax, numpy as np
 from jax.sharding import Mesh
+from repro.compile_cache import enable_compile_cache
 from repro.data.transactions import load
 from repro.core.tidlist import pack_database
 from repro.core.fpm import mine, mine_serial
 from repro.core.distributed_fpm import mine_distributed
 
+enable_compile_cache()
 db, p = load('mushroom', seed=0)
 db = db[:2500]
 bm = pack_database(db, p.n_dense_items)

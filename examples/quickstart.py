@@ -21,6 +21,7 @@ import argparse
 import sys
 sys.path.insert(0, "src")
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.fpm import mesh_over_devices, mine, mine_serial
 from repro.core.tidlist import pack_database
 from repro.data.transactions import load
@@ -104,4 +105,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

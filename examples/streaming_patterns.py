@@ -3,6 +3,7 @@ queries between (and during) refreshes.
 
     PYTHONPATH=src python examples/streaming_patterns.py
 """
+from repro.compile_cache import enable_compile_cache
 from repro.core.streaming import PatternServer, StreamingMiner
 from repro.data.transactions import load
 
@@ -46,4 +47,5 @@ def main():
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     main()

@@ -305,14 +305,11 @@ def mesh_over_devices(n: int):
     shared-memory run."""
     if n <= 1:
         return None
-    try:
-        import jax
-        from jax.sharding import Mesh
-        devs = jax.devices()
-        if len(devs) >= n:
-            return Mesh(np.array(devs[:n]), ("data",))
-    except Exception:       # pragma: no cover - jax always present here
-        pass
+    import jax
+    from jax.sharding import Mesh
+    devs = jax.devices()
+    if len(devs) >= n:
+        return Mesh(np.array(devs[:n]), ("data",))
     return n
 
 

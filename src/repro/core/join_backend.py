@@ -34,6 +34,7 @@ Backends implement the same batched API:
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from concurrent.futures import Future
@@ -44,6 +45,7 @@ import numpy as np
 
 from repro.core import tidlist
 from repro.core.tidlist import BitmapArena
+from repro.core.tidlist import pow2 as _pow2
 from repro.obs import schema as obs_schema
 
 # Dispatcher defaults: how many requests one kernel launch may carry,
@@ -307,18 +309,13 @@ class NumpyBackend(JoinBackend):
         return out
 
 
-def _pow2(n: int, lo: int = 1) -> int:
-    p = lo
-    while p < n:
-        p *= 2
-    return p
-
-
-# E-padding floor = the batched kernel's E tile (kernel.EB_TILE, not
-# imported to keep jax out of this module's import path): any narrower
-# pad would be re-padded to one tile inside the kernel anyway, so
-# distinct sub-tile shapes would only multiply jit compilations.
-E_PAD_FLOOR = 64
+# E-padding floor = the batched kernels' E tile (bitmap_join's EB_TILE
+# and gather_intersect's E_TILE, one 128-lane vreg; not imported to
+# keep jax out of this module's import path): any narrower pad would be
+# re-padded to one tile inside the kernel anyway, so distinct sub-tile
+# shapes would only multiply jit compilations. The sparse path's tid
+# axis pads to the same floor (one lane-dense SMEM block).
+E_PAD_FLOOR = 128
 
 
 class _PallasBackend(JoinBackend):
@@ -361,9 +358,7 @@ class _PallasBackend(JoinBackend):
         return totals
 
     def _sweep_segment(self, arena, seg, requests):
-        import jax.numpy as jnp
-
-        from repro.kernels.bitmap_join.ops import bitmap_join_many
+        gather, dense, _ = _flush_fns(self.mode)
         b = len(requests)
         emax = max(len(r.ext_handles) for r in requests)
         lmax = max(len(r.prefix_handles) for r in requests)
@@ -387,19 +382,13 @@ class _PallasBackend(JoinBackend):
             n = len(r.ext_handles)
             eidx[i, :n] = r.ext_handles
             mask[i, :n] = True
-        shard = requests[0].shard if requests else 0
-        needed = None
-        if arena.n_shards > 1:
-            needed = [h for r in requests
-                      for h in (*r.prefix_handles, *r.ext_handles)]
-        dev = arena.device_rows(shard, needed=needed, segment=seg)
+        dev = arena.device_rows(requests[0].shard,
+                                needed=self._needed(arena, requests),
+                                segment=seg)
         if dev is not None:
             # arena-gather path: bitmaps are already device-resident,
             # only the (tiny) index arrays cross host→device
-            if wp != w:
-                dev = jnp.pad(dev, ((0, 0), (0, wp - w)))
-            pr = dev[jnp.asarray(pidx.reshape(-1))].reshape(bp, lp, wp)
-            exts = dev[jnp.asarray(eidx.reshape(-1))].reshape(bp, ep, wp)
+            pr, exts = gather(dev, pidx, wp), gather(dev, eidx, wp)
         else:
             # host-gather baseline (arena backing "numpy"): the old
             # transfer-bound behaviour — every batch re-uploads its
@@ -409,17 +398,9 @@ class _PallasBackend(JoinBackend):
             ph = rows[pidx.reshape(-1)].reshape(bp, lp, w)
             eh = rows[eidx.reshape(-1)].reshape(bp, ep, w)
             arena.count_h2d(ph[:, 0].nbytes + eh.nbytes)
-            if wp != w:
-                ph = np.pad(ph, ((0, 0), (0, 0), (0, wp - w)))
-                eh = np.pad(eh, ((0, 0), (0, 0), (0, wp - w)))
-            pr = jnp.asarray(ph)
-            exts = jnp.asarray(eh)
-        prefixes = pr[:, 0, :]
-        for j in range(1, lp):        # tuple prefix: AND-reduce on device
-            prefixes = prefixes & pr[:, j, :]
-        return np.asarray(bitmap_join_many(prefixes, exts,
-                                           jnp.asarray(mask),
-                                           mode=self.mode))
+            pad = ((0, 0), (0, 0), (0, wp - w))
+            pr, exts = np.pad(ph, pad), np.pad(eh, pad)
+        return np.asarray(dense(pr, exts, mask))
 
     def _sweep_segment_sparse(self, arena, seg, requests):
         """Sparse sub-batch: prefixes are tid/diffset payloads, shipped
@@ -429,10 +410,7 @@ class _PallasBackend(JoinBackend):
         searchsorted down to this segment's global tid window and
         rebased, then padded to a pow2 S with the -1 sentinel so the
         jit cache stays bounded."""
-        import jax.numpy as jnp
-
-        from repro.kernels.gather_intersect.ops import (
-            gather_intersect_many)
+        gather, _, sparse = _flush_fns(self.mode)
         b = len(requests)
         emax = max(len(r.ext_handles) for r in requests)
         bp = _pow2(b)
@@ -458,27 +436,60 @@ class _PallasBackend(JoinBackend):
             n = len(r.ext_handles)
             eidx[i, :n] = r.ext_handles
             mask[i, :n] = True
-        shard = requests[0].shard if requests else 0
-        needed = None
-        if arena.n_shards > 1:
-            needed = [h for r in requests
-                      for h in (*r.prefix_handles, *r.ext_handles)]
-        dev = arena.device_rows(shard, needed=needed, segment=seg)
+        dev = arena.device_rows(requests[0].shard,
+                                needed=self._needed(arena, requests),
+                                segment=seg)
         if dev is not None:
-            if wp != w:
-                dev = jnp.pad(dev, ((0, 0), (0, wp - w)))
-            exts = dev[jnp.asarray(eidx.reshape(-1))].reshape(bp, ep, wp)
+            exts = gather(dev, eidx, wp)
             arena.count_h2d(tmat.nbytes)      # tid payload, per launch
         else:
             rows = arena.seg_view(seg)
             eh = rows[eidx.reshape(-1)].reshape(bp, ep, w)
             arena.count_h2d(eh.nbytes + tmat.nbytes)
-            if wp != w:
-                eh = np.pad(eh, ((0, 0), (0, 0), (0, wp - w)))
-            exts = jnp.asarray(eh)
-        return np.asarray(gather_intersect_many(jnp.asarray(tmat), exts,
-                                                jnp.asarray(mask),
-                                                mode=self.mode))
+            exts = np.pad(eh, ((0, 0), (0, 0), (0, wp - w)))
+        return np.asarray(sparse(tmat, exts, mask))
+
+    @staticmethod
+    def _needed(arena, requests):
+        """Handles a sharded mirror must hold for this batch (None on
+        one shard: every stale owned row is refreshed)."""
+        if arena.n_shards == 1:
+            return None
+        return [h for r in requests
+                for h in (*r.prefix_handles, *r.ext_handles)]
+
+
+@functools.lru_cache(maxsize=None)
+def _flush_fns(mode: str):
+    """The jitted programs of one kernel mode's flush, built once:
+    ``gather`` reads rows of an arena mirror by handle ([B, K] -> [B, K,
+    Wp], zero pad words), ``dense`` AND-reduces each request's prefix
+    tuple and runs ``bitmap_join_many``, ``sparse`` runs
+    ``gather_intersect_many``. Each compiles once per padded shape
+    (mirror capacity, B, L, E, S, W) — a handful per run — and a flush
+    is a few dispatches rather than a chain of eager ops."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels.bitmap_join.ops import bitmap_join_many
+    from repro.kernels.gather_intersect.ops import gather_intersect_many
+
+    @functools.partial(jax.jit, static_argnames=("wp",))
+    def gather(dev, idx, wp):
+        return jnp.pad(dev[idx], ((0, 0), (0, 0), (0, wp - dev.shape[1])))
+
+    @jax.jit
+    def dense(pr, exts, mask):
+        prefixes = pr[:, 0]
+        for j in range(1, pr.shape[1]):   # tuple prefix: AND-reduce
+            prefixes = prefixes & pr[:, j]
+        return bitmap_join_many(prefixes, exts, mask, mode=mode)
+
+    @jax.jit
+    def sparse(tids, exts, mask):
+        return gather_intersect_many(tids, exts, mask, mode=mode)
+
+    return gather, dense, sparse
 
 
 class PallasInterpretBackend(_PallasBackend):
@@ -510,11 +521,10 @@ def get_backend(name: str) -> JoinBackend:
 
 
 def _on_tpu() -> bool:
-    try:
-        import jax
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover - jax always present here
-        return False
+    """True when jax's default backend is a TPU. A backend that fails to
+    initialize raises here rather than reading as "no TPU"."""
+    import jax
+    return jax.default_backend() == "tpu"
 
 
 def available_backends() -> List[str]:
@@ -530,8 +540,9 @@ def resolve_backend(spec: str = "auto") -> JoinBackend:
     """One backend per run (batching replaced the per-bucket choice:
     narrow buckets now amortize a launch by sharing it, so there is no
     tiny-bucket penalty to route around). "auto" is the compiled
-    kernel on TPU and numpy on CPU — the interpreter is a correctness
-    tool, not a fast path."""
+    kernel when jax's default backend is a TPU and numpy otherwise —
+    the interpreter is a correctness tool, not a fast path. A named
+    backend that cannot run here raises; nothing falls back."""
     if spec == "auto":
         return get_backend("pallas-jit" if _on_tpu() else "numpy")
     avail = available_backends()

@@ -148,13 +148,9 @@ class _SnapshotIndex:
         n = len(self.items)
         if n == 0 or k <= 0 or plen >= self.enc.shape[1]:
             return []
-        order = vals = None
         if n >= TOPK_DEVICE_MIN:
-            try:
-                order, vals = self._device_top_k(prefix, k)
-            except Exception:            # no jax → host path
-                order = vals = None
-        if order is None:
+            order, vals = self._device_top_k(prefix, k)
+        else:
             mask = self.lens > plen
             if plen:
                 mask &= (self.enc[:, :plen]

@@ -9,6 +9,7 @@ re-expressed; DESIGN.md §3).
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from typing import List, Optional, Sequence, Tuple
@@ -20,6 +21,56 @@ WORD = 32
 
 def n_words(n_transactions: int) -> int:
     return (n_transactions + WORD - 1) // WORD
+
+
+# Device mirrors keep a power-of-two row capacity of at least this many
+# rows (see BitmapArena.device_rows).
+MIRROR_MIN_ROWS = 8
+
+
+def pow2(n: int, lo: int = 1) -> int:
+    """Smallest power-of-two multiple of ``lo`` that is >= ``n``."""
+    p = lo
+    while p < n:
+        p *= 2
+    return p
+
+
+@functools.lru_cache(maxsize=None)
+def _mirror_ops():
+    """The two jitted mirror updates, built once: ``write_rows`` lands a
+    block of fresh rows at a traced offset, ``set_rows`` scatters rows
+    by handle. Both keep the mirror's shape."""
+    import jax
+
+    @jax.jit
+    def write_rows(dev, rows, lo):
+        return jax.lax.dynamic_update_slice(dev, rows, (lo, np.int32(0)))
+
+    @jax.jit
+    def set_rows(dev, idx, rows):
+        return dev.at[idx].set(rows)
+
+    return write_rows, set_rows
+
+
+def _pad_rows(dev, cap: int):
+    """Grow a mirror buffer to ``cap`` rows with zero rows."""
+    import jax.numpy as jnp
+    return jnp.pad(dev, ((0, cap - dev.shape[0]), (0, 0)))
+
+
+def _pow2_set(handles: Sequence[int], rows: np.ndarray):
+    """Pad a (handles, rows) scatter to a power-of-two length by
+    repeating its first entry — a duplicate write of identical values,
+    so the result is unchanged while the scatter's shape stays in a
+    small set."""
+    k = pow2(len(handles))
+    idx = np.full(k, handles[0], np.int32)
+    idx[:len(handles)] = handles
+    out = np.repeat(rows[:1], k, axis=0)
+    out[:len(handles)] = rows
+    return idx, out
 
 
 def pack_database(db: Sequence[Sequence[int]], n_items: int,
@@ -488,8 +539,9 @@ class BitmapArena:
         blocks = [dev.get(g) for g in range(upto)]
         if nmin > 0 and all(b is not None for b in blocks):
             import jax.numpy as jnp
-            new_dev = _remap(dev, jnp.concatenate(
-                [b[:nmin] for b in blocks], axis=1))
+            new_dev = _remap(dev, _pad_rows(jnp.concatenate(
+                [b[:nmin] for b in blocks], axis=1),
+                pow2(nmin, lo=MIRROR_MIN_ROWS)))
         else:
             # nothing fully mirrored yet: the merged block re-syncs
             # from scratch on the next device_rows
@@ -1102,6 +1154,14 @@ class BitmapArena:
         synced incrementally (only that shard's dispatcher thread calls
         this). Returns None for host-only ("numpy") backing.
 
+        The mirror is a ``[cap, W_seg]`` buffer: rows ``[0, n_rows)``
+        mirror the store and the rest are zero padding, ``cap`` a power
+        of two (at least ``MIRROR_MIN_ROWS``). Its shape changes only
+        when the capacity doubles, so the gathers and updates that read
+        it compile a handful of times per run, not once per appended
+        row — on a chip, a per-flush recompile would cost more than the
+        sweep.
+
         ``needed`` lists the handles the caller is about to gather:
         foreign rows among them are fetched into this shard's mirror
         and counted in ``d2d_bytes``. Without ``needed`` (single-shard
@@ -1110,12 +1170,12 @@ class BitmapArena:
         "Incremental" bounds host→device PAYLOAD (the ``h2d_bytes``
         gauge): only changed rows cross the bus, and only this
         segment's words — an ingest that appended segment g uploads
-        ``seg_nbytes(g)``, never the older segments. The functional
-        update (concatenate / ``.at[].set``) still rebuilds the mirror
-        buffer on device, an O(n_rows) device-to-device copy per sync
-        with fresh rows — acceptable while mirrors are MBs; a donated
-        preallocated buffer would remove it when arenas reach device
-        memory scale."""
+        ``seg_nbytes(g)``, never the older segments (fresh rows pad to
+        a power-of-two count with zero rows, which are not billed). The
+        functional update still rebuilds the mirror buffer on device,
+        an O(cap) device-to-device copy per sync with fresh rows —
+        acceptable while mirrors are MBs; a donated buffer would remove
+        it when arenas reach device memory scale."""
         if not self.device_enabled:
             if needed is not None:
                 self.note_access(shard, needed, segments=(segment,))
@@ -1130,7 +1190,9 @@ class BitmapArena:
             store = self._stores[segment]
             fresh = None
             if n > lo:
-                fresh = store[lo:n].copy()
+                fresh = np.zeros((pow2(n - lo), store.shape[1]),
+                                 np.uint32)
+                fresh[:n - lo] = store[lo:n]
                 owned = set(fresh_owned)
                 for j, h in enumerate(range(lo, n)):
                     if h not in owned:
@@ -1141,36 +1203,40 @@ class BitmapArena:
                 for j, h in enumerate(fetch):
                     if self._rep[h] != REP_BITMAP:
                         fe_rows[j] = 0    # sparse slot: store words dead
+        import jax
         import jax.numpy as jnp
 
-        def _place(arr):
-            a = jnp.asarray(arr)
-            if self.devices is not None:
-                import jax
-                a = jax.device_put(a, self.devices[shard])
-            return a
+        device = self.devices[shard] if self.devices is not None else None
 
+        def _place(arr):
+            # straight from host memory to the shard's device: staging
+            # through jnp.asarray would land on the default device
+            # first and route every other shard's upload through it
+            if device is not None:
+                return jax.device_put(arr, device)
+            return jnp.asarray(arr)
+
+        write_rows, set_rows = _mirror_ops()
         row_bytes = self._seg_words[segment] * 4
         h2d_delta = 0
         dev = self._dev[shard].get(segment)
+        cap = pow2(max(n, lo + (len(fresh) if fresh is not None else 0)),
+                    lo=MIRROR_MIN_ROWS)
         if dev is None:
-            dev = _place(fresh if fresh is not None
-                         else store[:0])
-            h2d_delta += fresh_h2d * row_bytes
-        elif fresh is not None:
-            dev = jnp.concatenate([dev, _place(fresh)])
+            dev = jnp.zeros((cap, store.shape[1]), jnp.uint32,
+                            device=device)
+        elif dev.shape[0] < cap:
+            dev = _pad_rows(dev, cap)
+        if fresh is not None:
+            dev = write_rows(dev, _place(fresh), np.int32(lo))
             h2d_delta += fresh_h2d * row_bytes
         if re_rows is not None:
-            dev = dev.at[_place(np.asarray(reupload, np.int32))
-                         ].set(_place(re_rows))
+            dev = set_rows(dev, *map(_place, _pow2_set(reupload, re_rows)))
             h2d_delta += len(reupload) * row_bytes
         if fe_rows is not None:
             # payload already billed (d2d at fetch/migrate time) or
-            # dead/uncovered (no real payload); on this container's
-            # virtual devices the bits physically route through the
-            # host
-            dev = dev.at[_place(np.asarray(fetch, np.int32))
-                         ].set(_place(fe_rows))
+            # dead/uncovered (no real payload)
+            dev = set_rows(dev, *map(_place, _pow2_set(fetch, fe_rows)))
         self._dev[shard][segment] = dev
         if h2d_delta:
             self.count_h2d(h2d_delta, _traced=False)

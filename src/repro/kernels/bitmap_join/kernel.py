@@ -71,28 +71,33 @@ def bitmap_join_kernel(prefix: jnp.ndarray, exts: jnp.ndarray,
 # Multi-prefix (batched) variant: one grid launch for B coalesced sweeps
 # ---------------------------------------------------------------------------
 
-# The batched kernel serves dispatcher batches where most requests are
-# narrow (tens of extensions), so its E-tile is smaller than the
-# single-prefix kernel's: [64, 512] words = 128 KiB uint32 per exts
-# block, still lane-aligned (512 = 4×128) and VMEM-comfortable.
-EB_TILE = 64
+# The batched kernel's blocks obey the TPU tiling rule: the last two
+# dims of every block are multiples of (8, 128) or span the whole axis.
+# Prefixes and counts therefore carry a unit middle axis ([B, 1, W],
+# [B, 1, Ep]), so a block's batch row is a leading (untiled) dim and
+# its lane dim is a 128-multiple. [128, 512] words = 256 KiB uint32
+# per exts block; narrow segments (delta sweeps) shrink the W tile to
+# their width rounded up to one 128-lane vreg instead of padding to 512.
+EB_TILE = 128
 WB_TILE = 512
+LANES = 128
 
 
 def _many_kernel(prefixes_ref, exts_ref, out_ref):
+    # prefixes_ref: [1, 1, Wt]; exts_ref: [1, Et, Wt]; out_ref: [1, 1, Et]
     w_idx = pl.program_id(2)
 
     @pl.when(w_idx == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    p = prefixes_ref[...]                     # [1, Wt] uint32 (VMEM,
+    p = prefixes_ref[0]                       # [1, Wt] uint32 (VMEM,
                                               # resident across the
                                               # request's E sweep)
     e = exts_ref[0]                           # [Et, Wt] uint32
     joined = jnp.bitwise_and(e, p)            # broadcast over E
     counts = jax.lax.population_count(joined).astype(jnp.int32)
-    out_ref[0, :] += jnp.sum(counts, axis=1)
+    out_ref[0] += jnp.sum(counts, axis=1)[None, :]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -108,20 +113,22 @@ def bitmap_join_many_kernel(prefixes: jnp.ndarray, exts: jnp.ndarray,
     """
     b, e, w = exts.shape
     ep = (e + EB_TILE - 1) // EB_TILE * EB_TILE
-    wp = (w + WB_TILE - 1) // WB_TILE * WB_TILE
+    wt = min(WB_TILE, (w + LANES - 1) // LANES * LANES)
+    wp = (w + wt - 1) // wt * wt
     if (ep, wp) != (e, w):
         exts = jnp.pad(exts, ((0, 0), (0, ep - e), (0, wp - w)))
         prefixes = jnp.pad(prefixes, ((0, 0), (0, wp - w)))
-    grid = (b, ep // EB_TILE, wp // WB_TILE)
+    grid = (b, ep // EB_TILE, wp // wt)
     out = pl.pallas_call(
         _many_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, WB_TILE), lambda bi, i, j: (bi, j)),
-            pl.BlockSpec((1, EB_TILE, WB_TILE), lambda bi, i, j: (bi, i, j)),
+            pl.BlockSpec((1, 1, wt), lambda bi, i, j: (bi, 0, j)),
+            pl.BlockSpec((1, EB_TILE, wt), lambda bi, i, j: (bi, i, j)),
         ],
-        out_specs=pl.BlockSpec((1, EB_TILE), lambda bi, i, j: (bi, i)),
-        out_shape=jax.ShapeDtypeStruct((b, ep), jnp.int32),
+        out_specs=pl.BlockSpec((1, 1, EB_TILE), lambda bi, i, j: (bi, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((b, 1, ep), jnp.int32),
         interpret=interpret,
-    )(prefixes, exts)
-    return out[:, :e]
+        name="bitmap_join_many",
+    )(prefixes[:, None, :], exts)
+    return out[:, 0, :e]

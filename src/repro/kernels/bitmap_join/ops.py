@@ -1,4 +1,5 @@
-"""jit'd public wrapper: Pallas on TPU, interpret-mode or jnp on CPU.
+"""jit'd public wrapper: the compiled Pallas kernel, the interpreter,
+or the jnp oracle, by explicit mode.
 
 Compiled-function caching: the Pallas kernels are jitted once at module
 level (``kernel.py``), and the jnp reference paths go through
@@ -31,36 +32,33 @@ def _jitted(fn):
     return jax.jit(fn)
 
 
-def bitmap_join(prefix: jnp.ndarray, exts: jnp.ndarray,
-                *, use_pallas: bool | None = None,
-                interpret: bool | None = None,
-                mode: str = "auto") -> jnp.ndarray:
-    """Support counts of prefix∧ext for a cluster of extension bitmaps.
-
-    ``mode`` names an execution strategy explicitly (used by
-    ``repro.core.join_backend``): "ref" runs the jnp oracle, "pallas-jit"
-    compiles the Pallas kernel for the current backend, and
-    "pallas-interpret" runs the same kernel under the Pallas interpreter
-    (bit-exact with "pallas-jit", available on CPU). "auto" keeps the
-    legacy behaviour: Pallas on TPU, jnp ref elsewhere, unless the
-    ``use_pallas``/``interpret`` flags override it.
-    """
+def resolve_mode(mode: str) -> str:
+    """Validate ``mode`` and resolve "auto": the compiled kernel when
+    jax's default backend is a TPU, the jnp oracle otherwise. Every
+    other mode runs as named — "pallas-jit" off a TPU raises in the
+    Pallas lowering rather than falling back."""
     if mode not in MODES:
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
+    if mode == "auto":
+        return "pallas-jit" if jax.default_backend() == "tpu" else "ref"
+    return mode
+
+
+def bitmap_join(prefix: jnp.ndarray, exts: jnp.ndarray,
+                *, mode: str = "auto") -> jnp.ndarray:
+    """Support counts of prefix∧ext for a cluster of extension bitmaps.
+
+    ``mode`` names an execution strategy (see :func:`resolve_mode`):
+    "ref" runs the jnp oracle, "pallas-jit" compiles the Pallas kernel
+    for the current backend, and "pallas-interpret" runs the same
+    kernel under the Pallas interpreter (bit-exact with "pallas-jit",
+    available on CPU).
+    """
+    mode = resolve_mode(mode)
     if mode == "ref":
         return _jitted(bitmap_join_ref)(prefix, exts)
-    if mode == "pallas-interpret":
-        return bitmap_join_kernel(prefix, exts, interpret=True)
-    if mode == "pallas-jit":
-        return bitmap_join_kernel(prefix, exts, interpret=False)
-    on_tpu = jax.default_backend() == "tpu"
-    if use_pallas is None:
-        use_pallas = on_tpu
-    if not use_pallas:
-        return _jitted(bitmap_join_ref)(prefix, exts)
     return bitmap_join_kernel(prefix, exts,
-                              interpret=bool(interpret if interpret
-                                             is not None else not on_tpu))
+                              interpret=mode == "pallas-interpret")
 
 
 def bitmap_join_many(prefixes: jnp.ndarray, exts: jnp.ndarray,
@@ -73,20 +71,12 @@ def bitmap_join_many(prefixes: jnp.ndarray, exts: jnp.ndarray,
     dispatcher pads every request to E_max). One kernel launch covers
     all B requests — the dispatcher's coalescing unit.
     """
-    if mode not in MODES:
-        raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
+    mode = resolve_mode(mode)
     if mode == "ref":
         counts = _jitted(bitmap_join_many_ref)(prefixes, exts)
-    elif mode == "pallas-interpret":
-        counts = bitmap_join_many_kernel(prefixes, exts, interpret=True)
-    elif mode == "pallas-jit":
-        counts = bitmap_join_many_kernel(prefixes, exts, interpret=False)
-    else:                                     # auto: Pallas on TPU only
-        if jax.default_backend() == "tpu":
-            counts = bitmap_join_many_kernel(prefixes, exts,
-                                             interpret=False)
-        else:
-            counts = _jitted(bitmap_join_many_ref)(prefixes, exts)
+    else:
+        counts = bitmap_join_many_kernel(
+            prefixes, exts, interpret=mode == "pallas-interpret")
     if mask is not None:
         counts = jnp.where(mask, counts, 0)
     return counts

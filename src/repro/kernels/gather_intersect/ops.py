@@ -12,11 +12,10 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.bitmap_join.ops import resolve_mode
 from repro.kernels.gather_intersect.kernel import (
     gather_intersect_many_kernel)
 from repro.kernels.gather_intersect.ref import gather_intersect_many_ref
-
-MODES = ("auto", "ref", "pallas-interpret", "pallas-jit")
 
 
 @functools.lru_cache(maxsize=None)
@@ -34,23 +33,15 @@ def gather_intersect_many(tids: jnp.ndarray, exts: jnp.ndarray,
     zeroes padded extension lanes. An empty tid axis (S == 0) is the
     all-empty-intersection fast path — no launch at all.
     """
-    if mode not in MODES:
-        raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
+    mode = resolve_mode(mode)
     b, e, _ = exts.shape
     if tids.shape[1] == 0:
         return jnp.zeros((b, e), jnp.int32)
     if mode == "ref":
         counts = _jitted(gather_intersect_many_ref)(tids, exts)
-    elif mode == "pallas-interpret":
-        counts = gather_intersect_many_kernel(tids, exts, interpret=True)
-    elif mode == "pallas-jit":
-        counts = gather_intersect_many_kernel(tids, exts, interpret=False)
-    else:                                     # auto: Pallas on TPU only
-        if jax.default_backend() == "tpu":
-            counts = gather_intersect_many_kernel(tids, exts,
-                                                  interpret=False)
-        else:
-            counts = _jitted(gather_intersect_many_ref)(tids, exts)
+    else:
+        counts = gather_intersect_many_kernel(
+            tids, exts, interpret=mode == "pallas-interpret")
     if mask is not None:
         counts = jnp.where(mask, counts, 0)
     return counts

@@ -9,6 +9,7 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.buckets import REPRESENTATIONS
 from repro.core.fpm import (GRANULARITIES, mesh_over_devices, mine,
                             mine_serial)
@@ -34,10 +35,9 @@ def _finish_trace(args, tracer, wall_s: float) -> None:
 
 def _spawn_hosts(args) -> None:
     """Parent of a ``--hosts N`` run: pick a coordinator port, spawn
-    one rank subprocess per host with the CPU-cluster environment
-    (``JAX_PLATFORMS=cpu`` plus the collective-combine XLA thresholds
-    the big-model launchers tune, so a per-flush reduction fuses into
-    one transfer rather than many), forward rank 0's report, and
+    one rank subprocess per host with ``JAX_PLATFORMS=cpu`` (the ranks
+    emulate a cluster on this host's CPU; per-flush reductions travel
+    the coordination service's KV store), forward rank 0's report, and
     propagate the first failing exit code."""
     import os
     import socket
@@ -53,12 +53,6 @@ def _spawn_hosts(args) -> None:
         coord = f"127.0.0.1:{s.getsockname()[1]}"
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (
-        env.get("XLA_FLAGS", "")
-        + " --xla_gpu_all_reduce_combine_threshold_bytes=134217728"
-        + " --xla_gpu_all_gather_combine_threshold_bytes=134217728"
-        + " --xla_gpu_reduce_scatter_combine_threshold_bytes"
-        + "=134217728").strip()
     base = [sys.executable, "-m", "repro.launch.fpm_mine",
             "--dataset", args.dataset,
             "--workers", str(args.workers),
@@ -71,7 +65,7 @@ def _spawn_hosts(args) -> None:
     if args.support is not None:
         base += ["--support", str(args.support)]
     print(f"hosts: spawning {args.hosts} ranks @ {coord} "
-          f"(JAX_PLATFORMS=cpu, collective-combine XLA flags)")
+          f"(JAX_PLATFORMS=cpu)")
     procs = [subprocess.Popen(
         base + ["--_rank", str(r)], env=env,
         stdout=None if r == 0 else subprocess.DEVNULL)
@@ -125,7 +119,9 @@ def main():
                          "owns a word-slice of the transaction axis "
                          "and support counting is two-phase (local "
                          "partial counts + per-flush cross-host "
-                         "reduction). 0 = single process")
+                         "reduction). The ranks run on the CPU "
+                         "(JAX_PLATFORMS=cpu), never on an "
+                         "accelerator. 0 = single process")
     # child-rank plumbing for --hosts (set by the parent, not by hand)
     ap.add_argument("--_rank", type=int, default=None,
                     help=argparse.SUPPRESS)
@@ -162,6 +158,7 @@ def main():
                          "sweep, top-k) through the PatternServer and "
                          "print per-kind p50/p95/p99 (with --stream)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.hosts >= 2 and args._rank is None:
         return _spawn_hosts(args)
@@ -200,9 +197,9 @@ def main():
         print(f"mesh: {args.mesh} device shards "
               f"({'logical' if isinstance(mesh, int) else 'jax devices'})")
 
-    t0 = time.time()
+    t0 = time.perf_counter()
     ref = mine_serial(bitmaps, ms, max_k=args.max_k)
-    t_serial = time.time() - t0
+    t_serial = time.perf_counter() - t0
     print(f"serial: {len(ref)} frequent itemsets in {t_serial:.2f}s")
 
     tracer = (Tracer() if (args.trace or args.trace_summary)

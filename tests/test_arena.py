@@ -6,7 +6,7 @@ import pytest
 from repro.core import fpm as fpm_mod
 from repro.core.fpm import mine
 from repro.core.join_backend import NumpyBackend
-from repro.core.tidlist import BitmapArena, pack_database
+from repro.core.tidlist import MIRROR_MIN_ROWS, BitmapArena, pack_database
 
 RNG = np.random.default_rng(11)
 
@@ -91,12 +91,16 @@ def test_device_sync_is_incremental_and_counts_h2d():
     arena, rows = small_arena(n=4, w=8)
     row_bytes = 8 * 4
     dev = arena.device_rows()                # initial upload: 4 rows
-    assert dev.shape == (4, 8) and arena.h2d_bytes == 4 * row_bytes
+    # the mirror is a fixed-capacity buffer: 4 live rows + zero padding
+    assert dev.shape == (MIRROR_MIN_ROWS, 8)
+    assert arena.h2d_bytes == 4 * row_bytes
+    np.testing.assert_array_equal(np.asarray(dev)[:4], rows)
+    assert not np.asarray(dev)[4:].any()
     dev = arena.device_rows()                # no change -> no upload
     assert arena.h2d_bytes == 4 * row_bytes
     h = arena.push(rows[0] & rows[1])
     dev = arena.device_rows()                # one appended row
-    assert dev.shape == (5, 8)
+    assert dev.shape == (MIRROR_MIN_ROWS, 8)  # same shape: no recompile
     assert arena.h2d_bytes == 5 * row_bytes
     np.testing.assert_array_equal(np.asarray(dev[h]), rows[0] & rows[1])
     # recycled slot: freed row rewritten -> resynced as dirty, not
@@ -107,6 +111,23 @@ def test_device_sync_is_incremental_and_counts_h2d():
     dev = arena.device_rows()
     assert arena.h2d_bytes == 6 * row_bytes
     np.testing.assert_array_equal(np.asarray(dev[h2]), rows[2] | rows[3])
+
+
+def test_mirror_capacity_doubles_and_stays_exact():
+    """Appending rows one sync at a time changes the mirror's shape only
+    when its power-of-two capacity doubles (bounded recompiles), and the
+    live rows always equal the store."""
+    arena, rows = small_arena(n=4, w=8)
+    shapes = set()
+    for i in range(20):
+        arena.push(rows[i % 4] & rows[(i + 1) % 4])
+        dev = arena.device_rows()
+        shapes.add(dev.shape)
+        np.testing.assert_array_equal(np.asarray(dev)[:arena.n_rows],
+                                      arena.seg_view(0))
+        assert not np.asarray(dev)[arena.n_rows:].any()
+    assert shapes == {(8, 8), (16, 8), (32, 8)}
+    assert arena.h2d_bytes == 24 * 8 * 4      # padding rows not billed
 
 
 def test_numpy_backing_never_creates_device_mirror():
@@ -355,7 +376,7 @@ def test_segment_mirror_sync_bills_only_new_segment_bytes():
     dev1 = arena.device_rows(segment=1)
     assert arena.h2d_bytes == 4 * 8 * 4 + arena.seg_nbytes(1)
     assert arena.seg_nbytes(1) == 4 * 2 * 4
-    np.testing.assert_array_equal(np.asarray(dev1), seg)
+    np.testing.assert_array_equal(np.asarray(dev1)[:4], seg)
     arena.device_rows()                          # seg 0 unchanged:
     assert arena.h2d_bytes == 4 * 8 * 4 + 4 * 2 * 4   # no new upload
 

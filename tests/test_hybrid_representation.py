@@ -51,7 +51,10 @@ def _rand_sparse_batch(b, s, e, w, rng=RNG, ragged=True):
 
 
 @pytest.mark.parametrize("b,s,e,w", [(1, 7, 1, 2), (3, 16, 4, 3),
-                                     (5, 33, 2, 8), (2, 64, 6, 4)])
+                                     (5, 33, 2, 8), (2, 64, 6, 4),
+                                     # several W tiles and S tiles
+                                     (2, 1100, 3, 8200),
+                                     (3, 2500, 130, 9000)])
 def test_gather_intersect_interpret_matches_numpy_ref(b, s, e, w):
     jax = pytest.importorskip("jax")
     from repro.kernels.gather_intersect.kernel import (

@@ -204,7 +204,8 @@ def test_e_pad_floor_matches_kernel_tile():
     floor would mint distinct jit shapes the kernel re-pads to one tile
     anyway (pure compile-cache waste)."""
     from repro.kernels.bitmap_join.kernel import EB_TILE
-    assert jb.E_PAD_FLOOR == EB_TILE
+    from repro.kernels.gather_intersect.kernel import E_TILE
+    assert jb.E_PAD_FLOOR == EB_TILE == E_TILE
 
 
 def test_ops_mode_dispatch_parity():

@@ -45,7 +45,8 @@ def test_property_bitmap_join_random(e, w):
 
 # ------------------------------------------------- bitmap_join_many (batched)
 @pytest.mark.parametrize("b,e,w", [(1, 1, 1), (3, 7, 33), (2, 64, 512),
-                                   (5, 70, 600)])
+                                   (5, 70, 600), (3, 130, 700),
+                                   (33, 129, 1500)])
 def test_bitmap_join_many_shapes(b, e, w):
     prefixes = jnp.asarray(RNG.integers(0, 2 ** 32, size=(b, w),
                                         dtype=np.uint32))
