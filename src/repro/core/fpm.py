@@ -70,7 +70,7 @@ from repro.core.join_backend import (FLUSH_US, MAX_BATCH, SweepDispatcher,
                                      resolve_backend)
 from repro.core.scheduler import TaskScheduler, make_policy
 from repro.core.tidlist import BitmapArena
-from repro.obs import MetricsRegistry
+from repro.obs import MetricsRegistry, region
 from repro.obs import schema as obs_schema
 
 GRANULARITIES = ("bucket", "candidate", "depth-first", "auto")
@@ -521,9 +521,7 @@ class MiningRun:
                             or runtime.cluster is not None)
         # gauge baselines: zero for an owned runtime, the accumulated
         # counters for a borrowed one — finalize() reports deltas
-        self._disp0 = [(d.flushes, d.requests, d.queue_flushes,
-                        d.queue_requests, d.query_requests, d.sweep_s)
-                       for d in self.dispatchers]
+        self._disp0 = [d.stats() for d in self.dispatchers]
         self._sched0 = self.sched.merged_stats()
 
     def close(self) -> None:
@@ -533,14 +531,12 @@ class MiningRun:
             cache.drain()
 
     def _disp_stats(self, d, base) -> Dict[str, float]:
-        f0, r0, qf0, qr0, q0, s0 = base
+        now = d.stats()
         return obs_schema.device_stats(
-            {"device": d.shard, "flushes": d.flushes - f0,
-             "sweep_requests": d.requests - r0,
-             "query_requests": d.query_requests - q0,
-             "queue_flushes": d.queue_flushes - qf0,
-             "queue_requests": d.queue_requests - qr0,
-             "sweep_s": d.sweep_s - s0})
+            {**obs_schema.delta_counters(now, base,
+                                         obs_schema.DEVICE_COUNTERS),
+             "device": d.shard,
+             "sweep_s": now["sweep_s"] - base["sweep_s"]})
 
     def finalize(self, t0: float) -> MiningMetrics:
         """Fill the metrics from scheduler/dispatcher/arena gauges.
@@ -644,7 +640,11 @@ def mine(bitmaps: np.ndarray, min_support: int, *,
     traffic lands in ``MiningMetrics.net_bytes``/``steal_net``.
     ``trace`` attaches a :class:`repro.obs.Tracer`: workers,
     dispatchers and the arena record span timelines into it (export
-    with ``repro.obs.write_chrome_trace``; None = tracing off).
+    with ``repro.obs.write_chrome_trace``; None = tracing off). The
+    calling thread's ``driver`` lane tiles the call with ``mine.arena``,
+    ``mine.level1``, ``mine.start`` (the runtime), per level its
+    ``level.candidates`` and, when it has any, its ``level-k``, then
+    ``mine.close`` and ``mine.finalize``.
     """
     if hosts > 1:
         if mesh is not None:
@@ -658,25 +658,37 @@ def mine(bitmaps: np.ndarray, min_support: int, *,
                             max_batch=max_batch, flush_us=flush_us,
                             item_counts=item_counts, tracer=trace)
     n_shards, devices = _resolve_mesh(mesh)
-    store = BitmapArena.from_bitmaps(bitmaps, backing=arena,
-                                     n_shards=n_shards, devices=devices)
+    if trace is not None:
+        trace.set_lane("driver", sort_index=0)
+    with region(trace, "mine.arena", cat="level"):
+        # the host copy; the device mirror uploads on the first flush
+        # (eagerly here under arena="jax")
+        store = BitmapArena.from_bitmaps(bitmaps, backing=arena,
+                                         n_shards=n_shards,
+                                         devices=devices)
     t0 = time.perf_counter()
     # level 1 before the runtime spins up worker/dispatcher threads:
     # if it raises there is nothing to tear down
-    if item_counts is None:
-        item_counts = tidlist.popcount32(bitmaps).sum(axis=1)
-    result, frequent = _level1(bitmaps, min_support, counts=item_counts)
-    run = MiningRun(store, policy=policy, n_workers=n_workers,
-                    granularity=granularity, cache_size=cache_size,
-                    backend=backend, max_batch=max_batch,
-                    flush_us=flush_us, representation=representation,
-                    item_counts=item_counts, tracer=trace)
+    with region(trace, "mine.level1", cat="level"):
+        if item_counts is None:
+            item_counts = tidlist.popcount32(bitmaps).sum(axis=1)
+        result, frequent = _level1(bitmaps, min_support,
+                                   counts=item_counts)
+    with region(trace, "mine.start", cat="level"):
+        run = MiningRun(store, policy=policy, n_workers=n_workers,
+                        granularity=granularity, cache_size=cache_size,
+                        backend=backend, max_batch=max_batch,
+                        flush_us=flush_us,
+                        representation=representation,
+                        item_counts=item_counts, tracer=trace)
     run.metrics.frequent += len(frequent)
     try:
         mine_more(run, min_support, max_k, result, frequent)
     finally:
-        run.close()
-    return result, run.finalize(t0)
+        with region(trace, "mine.close", cat="level"):
+            run.close()
+    with region(trace, "mine.finalize", cat="level"):
+        return result, run.finalize(t0)
 
 
 def mine_more(run: MiningRun, min_support: int, max_k: int,
@@ -749,6 +761,7 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
     upto = ((max(delta.base_segments) + 1)
             if delta is not None and delta.base_segments else None)
     lock = threading.Lock()
+    tr = sched.tracer
     df_miner = None
     detached_tasks: List = []
     if granularity == "auto" and model is not None and delta is None:
@@ -854,34 +867,40 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
                             own_support, True)
 
     def _spawn_buckets(cands, segments):
-        plan = group_by_prefix(cands)
-        if df_miner is not None:
-            keep = []
-            for b in plan:
-                ps = result.get(b.prefix)
-                if (ps is not None
-                        and model.pick_granularity(ps) == "depth-first"):
-                    # the class task re-counts its own candidates
-                    metrics.candidates -= len(b.exts)
-                    # parent-level sibling supports (for dEclat
-                    # children): support of prefix[:-1] + (e,), frequent
-                    # by the Apriori prune so present in ``result``
-                    psup = tuple(result[b.prefix[:-1] + (e,)]
-                                 for e in b.exts)
-                    detached_tasks.append(
-                        sched.spawn(detach_task, b, ps, psup,
-                                    attr=(b.key, b.prefix)))
-                else:
-                    keep.append(b)
-            plan = keep
-        metrics.buckets += len(plan)
-        prio = delta.priority_of if delta is not None else None
-        tenant = delta.tenant if delta is not None else None
-        tasks = [sched.spawn(sweep_task, b, segments,
-                             attr=(b.key, b.prefix),
-                             priority=prio(b.prefix) if prio else 0.0,
-                             tenant=tenant)
-                 for b in plan]
+        with region(tr, "level.plan", cat="level"):
+            plan = group_by_prefix(cands)
+            detach = []
+            if df_miner is not None:
+                keep = []
+                for b in plan:
+                    ps = result.get(b.prefix)
+                    if (ps is not None and model.pick_granularity(ps)
+                            == "depth-first"):
+                        # the class task re-counts its own candidates
+                        metrics.candidates -= len(b.exts)
+                        # parent-level sibling supports (for dEclat
+                        # children): support of prefix[:-1] + (e,),
+                        # frequent by the Apriori prune so present in
+                        # ``result``
+                        psup = tuple(result[b.prefix[:-1] + (e,)]
+                                     for e in b.exts)
+                        detach.append((b, ps, psup))
+                    else:
+                        keep.append(b)
+                plan = keep
+        with region(tr, "level.spawn", cat="level"):
+            detached_tasks.extend(
+                sched.spawn(detach_task, b, ps, psup,
+                            attr=(b.key, b.prefix))
+                for b, ps, psup in detach)
+            metrics.buckets += len(plan)
+            prio = delta.priority_of if delta is not None else None
+            tenant = delta.tenant if delta is not None else None
+            tasks = [sched.spawn(sweep_task, b, segments,
+                                 attr=(b.key, b.prefix),
+                                 priority=prio(b.prefix) if prio else 0.0,
+                                 tenant=tenant)
+                     for b in plan]
         return plan, tasks
 
     def _spawn_candidates(cands, segments):
@@ -962,7 +981,8 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
                         for b, t in zip(plan, tasks)
                         for e, s in zip(b.exts, t.result)]
         else:
-            tasks = _spawn_candidates(cands, segments)
+            with region(tr, "level.spawn", cat="level"):
+                tasks = _spawn_candidates(cands, segments)
 
             def collect():
                 _raise_task_errors(tasks)
@@ -970,86 +990,110 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
                         for c, t in zip(cands, tasks)]
         return collect
 
-    k = 2
-    tr = sched.tracer
-    while frequent and k <= max_k:
-        t_level = tr.now() if tr is not None else 0.0
-        # detached subtrees' itemsets never rejoin ``frequent``, so the
-        # Apriori prune needs the full known-frequent membership (the
-        # result dict is complete here: the level barrier below also
-        # waited on every detached class task)
-        cands = (gen_candidates(frequent, known_frequent=result)
-                 if df_miner is not None else gen_candidates(frequent))
-        if not cands:
-            break
-        metrics.levels += 1
-        metrics.candidates += len(cands)
+    def _keep_frequent(level: List[Tuple[Itemset, int]]
+                       ) -> List[Itemset]:
+        """Threshold one level's counted candidates into ``result``;
+        the level's frequent itemsets, sorted."""
         frequent = []
-        level: List[Tuple[Itemset, int]] = []
-        if delta is None:
-            collect = _spawn_sweeps(cands, None)
-            if cluster is None:
-                sched.wait_all()
-            else:
-                cluster.level_wait(sched)
-            if df_miner is not None:
-                _raise_task_errors(detached_tasks)
-                df_miner.raise_errors()
-            level = collect()
-            if cluster is not None:
-                level = cluster.exchange(level)
-        else:
-            clean, dirty, fresh = delta.classify_buckets(
-                group_by_prefix(cands))
-            level.extend(clean)                 # clean: zero rows read
-            if cluster is None or cluster.host_id == 0:
-                # a loopback cluster SHARES the plan: bill its
-                # avoided-work counters once, not once per host
-                delta.reused += len(clean)
-                delta.swept_full += len(fresh)
-                delta.swept_delta += sum(len(b.exts) for b in dirty)
-            if cluster is not None:
-                dirty = [b for b in dirty if cluster.owns(b.prefix)]
-            collect_fresh = _spawn_sweeps(fresh, delta.base_segments)
-            collect_dirty = _spawn_delta_chunks(dirty)
-            if cluster is None:
-                sched.wait_all()
-                for c, s in collect_fresh():
-                    delta.known[c] = s
-                    level.append((c, s))
-                for c, d in collect_dirty():
-                    s = delta.known[c] + d      # delta over pending segs
-                    delta.known[c] = s
-                    level.append((c, s))
-            else:
-                cluster.level_wait(sched)
-                mined = ([(c, s, True) for c, s in collect_fresh()]
-                         + [(c, d, False) for c, d in collect_dirty()])
-
-                def _apply(merged):
-                    # runs ONCE per known-store (host 0 under loopback,
-                    # where hosts share the plan): fold fresh supports
-                    # and dirty deltas into ``known``, return the
-                    # globally-thresholdable (itemset, support) pairs
-                    out = []
-                    for c, v, is_fresh in merged:
-                        s = v if is_fresh else delta.known[c] + v
-                        delta.known[c] = s
-                        out.append((c, s))
-                    return out
-
-                level.extend(cluster.exchange(mined, update=_apply))
         for c, s in level:
             if s >= min_support:
                 result[c] = s
                 frequent.append(c)
         frequent.sort()
         metrics.frequent += len(frequent)
-        if tr is not None:
-            # driver-lane level span: the barrier-to-barrier extent
-            tr.span(f"level-{k}", t_level, cat="level",
-                    args={"candidates": len(cands),
-                          "frequent": len(frequent)})
+        return frequent
+
+    def _level_wait() -> None:
+        with region(tr, "level.barrier", cat="idle"):
+            if cluster is None:
+                sched.wait_all()
+            else:
+                cluster.level_wait(sched)
+
+    # driver-lane spans: each level's level.candidates, then, for a
+    # level with candidates, level-k tiled into plan, spawn, barrier
+    # and collect (the last thresholds and sorts)
+    k = 2
+    while frequent and k <= max_k:
+        with region(tr, "level.candidates", cat="level"):
+            # detached subtrees' itemsets never rejoin ``frequent``, so
+            # the Apriori prune needs the full known-frequent membership
+            # (the result dict is complete here: the level barrier
+            # below also waited on every detached class task)
+            cands = (gen_candidates(frequent, known_frequent=result)
+                     if df_miner is not None else gen_candidates(frequent))
+        if not cands:
+            break
+        with region(tr, f"level-{k}", cat="level") as level_args:
+            metrics.levels += 1
+            metrics.candidates += len(cands)
+            level: List[Tuple[Itemset, int]] = []
+            if delta is None:
+                collect = _spawn_sweeps(cands, None)
+                _level_wait()
+                with region(tr, "level.collect", cat="level"):
+                    if df_miner is not None:
+                        _raise_task_errors(detached_tasks)
+                        df_miner.raise_errors()
+                    level = collect()
+                    if cluster is not None:
+                        level = cluster.exchange(level)
+                    frequent = _keep_frequent(level)
+            else:
+                with region(tr, "level.plan", cat="level"):
+                    clean, dirty, fresh = delta.classify_buckets(
+                        group_by_prefix(cands))
+                    level.extend(clean)         # clean: zero rows read
+                    if cluster is None or cluster.host_id == 0:
+                        # a loopback cluster SHARES the plan: bill its
+                        # avoided-work counters once, not once per host
+                        delta.reused += len(clean)
+                        delta.swept_full += len(fresh)
+                        delta.swept_delta += sum(len(b.exts)
+                                                 for b in dirty)
+                    if cluster is not None:
+                        dirty = [b for b in dirty
+                                 if cluster.owns(b.prefix)]
+                collect_fresh = _spawn_sweeps(fresh, delta.base_segments)
+                with region(tr, "level.spawn", cat="level"):
+                    collect_dirty = _spawn_delta_chunks(dirty)
+                _level_wait()
+                with region(tr, "level.collect", cat="level"):
+                    if cluster is None:
+                        for c, s in collect_fresh():
+                            delta.known[c] = s
+                            level.append((c, s))
+                        for c, d in collect_dirty():
+                            # delta over pending segs
+                            s = delta.known[c] + d
+                            delta.known[c] = s
+                            level.append((c, s))
+                    else:
+                        mined = ([(c, s, True)
+                                  for c, s in collect_fresh()]
+                                 + [(c, d, False)
+                                    for c, d in collect_dirty()])
+
+                        def _apply(merged):
+                            # runs ONCE per known-store (host 0 under
+                            # loopback, where hosts share the plan):
+                            # fold fresh supports and dirty deltas into
+                            # ``known``, return the globally-
+                            # thresholdable (itemset, support) pairs
+                            out = []
+                            for c, v, is_fresh in merged:
+                                s = (v if is_fresh
+                                     else delta.known[c] + v)
+                                delta.known[c] = s
+                                out.append((c, s))
+                            return out
+
+                        level.extend(cluster.exchange(mined,
+                                                      update=_apply))
+                    frequent = _keep_frequent(level)
+            if level_args is not None:
+                level_args.update(candidates=len(cands),
+                                  frequent=len(frequent))
         k += 1
 
 

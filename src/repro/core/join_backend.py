@@ -46,6 +46,7 @@ import numpy as np
 from repro.core import tidlist
 from repro.core.tidlist import BitmapArena
 from repro.core.tidlist import pow2 as _pow2
+from repro.obs import region
 from repro.obs import schema as obs_schema
 
 # Dispatcher defaults: how many requests one kernel launch may carry,
@@ -91,6 +92,8 @@ class SweepRequest:
     queue (guaranteed into the next flush) and caps the dispatcher's
     straggler wait at ``QUERY_FLUSH_US`` — queries coalesce with
     candidate sweeps but never wait out the full mining window.
+    ``t_submit`` (``perf_counter``) starts the request's queue wait,
+    which ends when the flush that carries it starts.
 
     ``desc`` is the request's portable descriptor for multi-host runs:
     the prefix as base ITEM ids, meaningful on any host's arena slice.
@@ -106,6 +109,7 @@ class SweepRequest:
     priority: bool = False
     desc: Optional[Tuple[int, ...]] = None
     future: Future = field(default_factory=Future)
+    t_submit: float = field(default_factory=time.perf_counter)
 
     @property
     def prefix_handles(self) -> Tuple[int, ...]:
@@ -123,6 +127,23 @@ class SweepRequest:
         p = self.prefix_handle
         return (not isinstance(p, tuple)
                 and arena.rep_of(p) != tidlist.REP_BITMAP)
+
+
+class _DispatchThread(threading.local):
+    """What a backend reads on the calling dispatcher thread: that
+    dispatcher's per-kernel counters (``SweepDispatcher.work``) and its
+    tracer, for the ``flush.*`` spans on its lane. Elsewhere
+    (an inline host burst, or a backend called directly) both stay
+    None, and nothing is counted or traced. Per thread, so one shared
+    backend serves every shard's dispatcher and ``sweep_many(arena,
+    requests)`` keeps the two-argument form that backend subclasses
+    and wrappers override."""
+
+    work: Optional[Dict[str, int]] = None
+    tracer = None
+
+
+_dispatch_thread = _DispatchThread()
 
 
 class JoinBackend:
@@ -156,7 +177,9 @@ class NumpyBackend(JoinBackend):
     tier-1 tests exercise the identical request/batch/flush
     machinery. In sharded mode the batch's row accesses are booked
     against the requests' shard first (cross-shard reads land in the
-    arena's ``d2d_bytes`` gauge)."""
+    arena's ``d2d_bytes`` gauge). On a dispatcher thread the passes are
+    the flush's one ``flush.launch`` span; they run synchronously, so
+    the flush has no ``flush.wait``."""
 
     name = "numpy"
     host_parallel = True
@@ -164,6 +187,13 @@ class NumpyBackend(JoinBackend):
     PASS_BYTES = 4 << 20
 
     def sweep_many(self, arena, requests):
+        with region(_dispatch_thread.tracer, "flush.launch",
+                    cat="flush") as args:
+            if args is not None:
+                args["kernel"] = "numpy"
+            return self._sweep(arena, requests)
+
+    def _sweep(self, arena, requests):
         if arena.n_shards > 1:
             # booked per request: batches are shard-homogeneous today
             # (each dispatcher stamps its own shard), but a mixed batch
@@ -326,11 +356,21 @@ class _PallasBackend(JoinBackend):
     back out and sum them across segments. B and E pad to powers of
     two so the jit cache stays bounded (~log × log shapes per run);
     single-segment arenas (every non-streaming run) keep the one-launch
-    behaviour."""
+    behaviour.
+
+    On a dispatcher thread each launch is three ``flush.*`` spans on
+    its lane — ``flush.prepare`` (index and tid arrays, mirror sync),
+    ``flush.launch`` (the jitted calls, enqueued without waiting) and
+    ``flush.wait`` (the copy of the counts back, which waits for the
+    device) — and adds its logical and padded work to the dispatcher's
+    counters: dense words read ``Σ (L + E) × W`` against
+    ``B' × (L' + E') × W'``, sparse probes ``Σ S × E`` against
+    ``B' × S' × E'``."""
 
     mode = "pallas-interpret"
 
     def sweep_many(self, arena, requests):
+        work, tracer = _dispatch_thread.work, _dispatch_thread.tracer
         totals = [np.zeros(len(r.ext_handles), np.int64)
                   for r in requests]
         # sub-batch per segment: full sweeps touch every segment, delta
@@ -351,58 +391,73 @@ class _PallasBackend(JoinBackend):
                              (sparse, self._sweep_segment_sparse)):
                 if not part:
                     continue
-                counts = fn(arena, g, [requests[i] for i in part])
+                counts = fn(arena, g, [requests[i] for i in part],
+                            work, tracer)
                 for j, i in enumerate(part):
                     totals[i] += counts[j, :len(requests[i].ext_handles)
                                         ].astype(np.int64)
         return totals
 
-    def _sweep_segment(self, arena, seg, requests):
+    def _sweep_segment(self, arena, seg, requests, work, tracer):
         gather, dense, _ = _flush_fns(self.mode)
-        b = len(requests)
-        emax = max(len(r.ext_handles) for r in requests)
-        lmax = max(len(r.prefix_handles) for r in requests)
-        bp = _pow2(b)
-        ep = _pow2(emax, lo=E_PAD_FLOOR)
-        lp = _pow2(lmax)
-        w = arena.seg_words(seg)
-        # pad W to a pow2 too: delta sweeps see one fresh W per ingest,
-        # and without the pad every (segment width, shape) pair mints a
-        # new jit cache entry — recompile stalls that grow with ingest
-        # count. Zero pad words AND to zero and add no popcount.
-        wp = _pow2(w)
-        pidx = np.zeros((bp, lp), np.int32)
-        eidx = np.zeros((bp, ep), np.int32)
-        mask = np.zeros((bp, ep), bool)
-        for i, r in enumerate(requests):
-            ph = r.prefix_handles
-            # pad the prefix tuple by repeating its first handle —
-            # AND-idempotent, so no mask dimension is needed
-            pidx[i] = (ph + (ph[0],) * (lp - len(ph)))
-            n = len(r.ext_handles)
-            eidx[i, :n] = r.ext_handles
-            mask[i, :n] = True
-        dev = arena.device_rows(requests[0].shard,
-                                needed=self._needed(arena, requests),
-                                segment=seg)
-        if dev is not None:
-            # arena-gather path: bitmaps are already device-resident,
-            # only the (tiny) index arrays cross host→device
-            pr, exts = gather(dev, pidx, wp), gather(dev, eidx, wp)
-        else:
-            # host-gather baseline (arena backing "numpy"): the old
-            # transfer-bound behaviour — every batch re-uploads its
-            # bitmap payload, and the gauge records it (pad words are
-            # synthetic zeros, not billed)
-            rows = arena.seg_view(seg)
-            ph = rows[pidx.reshape(-1)].reshape(bp, lp, w)
-            eh = rows[eidx.reshape(-1)].reshape(bp, ep, w)
-            arena.count_h2d(ph[:, 0].nbytes + eh.nbytes)
-            pad = ((0, 0), (0, 0), (0, wp - w))
-            pr, exts = np.pad(ph, pad), np.pad(eh, pad)
-        return np.asarray(dense(pr, exts, mask))
+        with region(tracer, "flush.prepare", cat="flush"):
+            b = len(requests)
+            emax = max(len(r.ext_handles) for r in requests)
+            lmax = max(len(r.prefix_handles) for r in requests)
+            bp = _pow2(b)
+            ep = _pow2(emax, lo=E_PAD_FLOOR)
+            lp = _pow2(lmax)
+            w = arena.seg_words(seg)
+            # pad W to a pow2 too: delta sweeps see one fresh W per
+            # ingest, and without the pad every (segment width, shape)
+            # pair mints a new jit cache entry — recompile stalls that
+            # grow with ingest count. Zero pad words AND to zero and add
+            # no popcount.
+            wp = _pow2(w)
+            pidx = np.zeros((bp, lp), np.int32)
+            eidx = np.zeros((bp, ep), np.int32)
+            mask = np.zeros((bp, ep), bool)
+            for i, r in enumerate(requests):
+                ph = r.prefix_handles
+                # pad the prefix tuple by repeating its first handle —
+                # AND-idempotent, so no mask dimension is needed
+                pidx[i] = (ph + (ph[0],) * (lp - len(ph)))
+                n = len(r.ext_handles)
+                eidx[i, :n] = r.ext_handles
+                mask[i, :n] = True
+            dev = arena.device_rows(requests[0].shard,
+                                    needed=self._needed(arena, requests),
+                                    segment=seg)
+            if dev is None:
+                # host-gather baseline (arena backing "numpy"): the old
+                # transfer-bound behaviour — every batch re-uploads its
+                # bitmap payload, and the gauge records it (pad words
+                # are synthetic zeros, not billed)
+                rows = arena.seg_view(seg)
+                ph = rows[pidx.reshape(-1)].reshape(bp, lp, w)
+                eh = rows[eidx.reshape(-1)].reshape(bp, ep, w)
+                arena.count_h2d(ph[:, 0].nbytes + eh.nbytes)
+                pad = ((0, 0), (0, 0), (0, wp - w))
+                pr, exts = np.pad(ph, pad), np.pad(eh, pad)
+        with region(tracer, "flush.launch", cat="flush") as a:
+            if a is not None:
+                a["kernel"] = "bitmap_join"
+            if dev is not None:
+                # arena-gather path: bitmaps are already device-resident,
+                # only the (tiny) index arrays cross host→device
+                pr, exts = gather(dev, pidx, wp), gather(dev, eidx, wp)
+            out = dense(pr, exts, mask)
+        with region(tracer, "flush.wait", cat="flush"):
+            counts = np.asarray(out)
+        if work is not None:
+            work["bitmap_join_launches"] += 1
+            work["bitmap_join_words"] += w * sum(
+                len(r.prefix_handles) + len(r.ext_handles)
+                for r in requests)
+            work["bitmap_join_padded_words"] += bp * (lp + ep) * wp
+        return counts
 
-    def _sweep_segment_sparse(self, arena, seg, requests):
+    def _sweep_segment_sparse(self, arena, seg, requests, work, tracer):
         """Sparse sub-batch: prefixes are tid/diffset payloads, shipped
         host→device per launch (billed at actual nbytes — sparse rows
         have no resident mirror payload); extension word-columns gather
@@ -411,43 +466,57 @@ class _PallasBackend(JoinBackend):
         rebased, then padded to a pow2 S with the -1 sentinel so the
         jit cache stays bounded."""
         gather, _, sparse = _flush_fns(self.mode)
-        b = len(requests)
-        emax = max(len(r.ext_handles) for r in requests)
-        bp = _pow2(b)
-        ep = _pow2(emax, lo=E_PAD_FLOOR)
-        w = arena.seg_words(seg)
-        wp = _pow2(w)
-        lo, hi = arena.seg_tid_range(seg)
-        local: List[np.ndarray] = []
-        smax = 1
-        for r in requests:
-            tids = arena.tids_of(r.prefix_handle)
-            i0, i1 = np.searchsorted(tids, [lo, hi])
-            t = (tids[i0:i1].astype(np.int64) - lo).astype(np.int32)
-            local.append(t)
-            smax = max(smax, len(t))
-        sp = _pow2(smax, lo=E_PAD_FLOOR)
-        tmat = np.full((bp, sp), -1, np.int32)
-        for i, t in enumerate(local):
-            tmat[i, :len(t)] = t
-        eidx = np.zeros((bp, ep), np.int32)
-        mask = np.zeros((bp, ep), bool)
-        for i, r in enumerate(requests):
-            n = len(r.ext_handles)
-            eidx[i, :n] = r.ext_handles
-            mask[i, :n] = True
-        dev = arena.device_rows(requests[0].shard,
-                                needed=self._needed(arena, requests),
-                                segment=seg)
-        if dev is not None:
-            exts = gather(dev, eidx, wp)
-            arena.count_h2d(tmat.nbytes)      # tid payload, per launch
-        else:
-            rows = arena.seg_view(seg)
-            eh = rows[eidx.reshape(-1)].reshape(bp, ep, w)
-            arena.count_h2d(eh.nbytes + tmat.nbytes)
-            exts = np.pad(eh, ((0, 0), (0, 0), (0, wp - w)))
-        return np.asarray(sparse(tmat, exts, mask))
+        with region(tracer, "flush.prepare", cat="flush"):
+            b = len(requests)
+            emax = max(len(r.ext_handles) for r in requests)
+            bp = _pow2(b)
+            ep = _pow2(emax, lo=E_PAD_FLOOR)
+            w = arena.seg_words(seg)
+            wp = _pow2(w)
+            lo, hi = arena.seg_tid_range(seg)
+            local: List[np.ndarray] = []
+            smax = 1
+            for r in requests:
+                tids = arena.tids_of(r.prefix_handle)
+                i0, i1 = np.searchsorted(tids, [lo, hi])
+                t = (tids[i0:i1].astype(np.int64) - lo).astype(np.int32)
+                local.append(t)
+                smax = max(smax, len(t))
+            sp = _pow2(smax, lo=E_PAD_FLOOR)
+            tmat = np.full((bp, sp), -1, np.int32)
+            for i, t in enumerate(local):
+                tmat[i, :len(t)] = t
+            eidx = np.zeros((bp, ep), np.int32)
+            mask = np.zeros((bp, ep), bool)
+            for i, r in enumerate(requests):
+                n = len(r.ext_handles)
+                eidx[i, :n] = r.ext_handles
+                mask[i, :n] = True
+            dev = arena.device_rows(requests[0].shard,
+                                    needed=self._needed(arena, requests),
+                                    segment=seg)
+            if dev is not None:
+                arena.count_h2d(tmat.nbytes)    # tid payload, per launch
+            else:
+                rows = arena.seg_view(seg)
+                eh = rows[eidx.reshape(-1)].reshape(bp, ep, w)
+                arena.count_h2d(eh.nbytes + tmat.nbytes)
+                exts = np.pad(eh, ((0, 0), (0, 0), (0, wp - w)))
+        with region(tracer, "flush.launch", cat="flush") as a:
+            if a is not None:
+                a["kernel"] = "gather_intersect"
+            if dev is not None:
+                exts = gather(dev, eidx, wp)
+            out = sparse(tmat, exts, mask)
+        with region(tracer, "flush.wait", cat="flush"):
+            counts = np.asarray(out)
+        if work is not None:
+            work["gather_intersect_launches"] += 1
+            work["gather_intersect_probes"] += sum(
+                len(t) * len(r.ext_handles)
+                for t, r in zip(local, requests))
+            work["gather_intersect_padded_probes"] += bp * sp * ep
+        return counts
 
     @staticmethod
     def _needed(arena, requests):
@@ -621,6 +690,11 @@ class SweepDispatcher:
         # since inline bursts never mix with anything by construction
         self.queue_flushes = 0
         self.queue_requests = 0
+        # µs queued requests waited from submit to the start of their
+        # flush, summed; and the per-kernel launch counters the
+        # backend adds to (only this dispatcher's thread writes either)
+        self.queue_wait_us = 0
+        self.work = dict.fromkeys(obs_schema.KERNEL_COUNTERS, 0)
         self._thread = threading.Thread(target=self._loop, daemon=True,
                                         name=f"sweep-dispatcher-{shard}")
         self._thread.start()
@@ -713,18 +787,17 @@ class SweepDispatcher:
                 raise RuntimeError("dispatcher is stopped")
             self.flushes += 1
             self.requests += len(reqs)
-        t0 = time.perf_counter()
-        results = self.backend.sweep_many(self.arena, reqs)
-        with self._cv:
-            self.sweep_s += time.perf_counter() - t0
-        if self.cluster is not None:
-            results = self.cluster.reduce_flush(reqs, results)
-        tr = self.tracer
-        if tr is not None:
-            # inline burst: the flush span lands on the CALLING
-            # worker's lane (that is where the time went)
-            tr.span("flush", t0, cat="flush",
-                    args=self._flush_args(reqs, inline=True))
+        # inline burst: the flush span lands on the CALLING worker's
+        # lane (that is where the time went)
+        with region(self.tracer, "flush", cat="flush") as args:
+            t0 = time.perf_counter()
+            results = self.backend.sweep_many(self.arena, reqs)
+            with self._cv:
+                self.sweep_s += time.perf_counter() - t0
+            if self.cluster is not None:
+                results = self.cluster.reduce_flush(reqs, results)
+            if args is not None:
+                args.update(self._flush_args(reqs, inline=True))
         return results
 
     def sweep(self, prefix_handle: int,
@@ -734,17 +807,12 @@ class SweepDispatcher:
         """Blocking convenience: enqueue and wait for the counts.
         ``segments`` restricts the join to a segment subset (a
         streaming delta sweep)."""
-        tr = self.tracer
-        if tr is None:
+        # caller-side wait: nests inside the worker's task span
+        with region(self.tracer, "sweep", cat="sweep") as args:
+            if args is not None:
+                args["ext"] = len(ext_handles)
             return self.submit(prefix_handle, ext_handles,
                                segments=segments, desc=desc).result()
-        t0 = tr.now()
-        counts = self.submit(prefix_handle, ext_handles,
-                             segments=segments, desc=desc).result()
-        # caller-side wait: nests inside the worker's task span
-        tr.span("sweep", t0, cat="sweep",
-                args={"ext": len(ext_handles)})
-        return counts
 
     def sweep_bits(self, prefix_handle: int, ext_handles: Sequence[int],
                    desc: Optional[Tuple[int, ...]] = None
@@ -775,28 +843,22 @@ class SweepDispatcher:
                 raise RuntimeError("dispatcher is stopped")
             self.flushes += 1
             self.requests += 1
-        tr = self.tracer
-        if req.is_sparse(self.arena) and getattr(
-                self.backend, "sweep_sparse_bits", None) is not None:
-            if self.arena.n_shards > 1:
-                self.arena.note_access(req.shard, (*req.prefix_handles,
-                                                   *req.ext_handles))
+        sparse = req.is_sparse(self.arena) and getattr(
+            self.backend, "sweep_sparse_bits", None) is not None
+        if sparse and self.arena.n_shards > 1:
+            self.arena.note_access(req.shard, (*req.prefix_handles,
+                                               *req.ext_handles))
+        with region(self.tracer, "sweep", cat="sweep") as args:
+            if args is not None:
+                args.update(ext=len(req.ext_handles), sparse=sparse)
+            if sparse:
+                return self.backend.sweep_sparse_bits(self.arena, req)
             t0 = time.perf_counter()
-            out = self.backend.sweep_sparse_bits(self.arena, req)
-            if tr is not None:
-                tr.span("sweep", t0, cat="sweep",
-                        args={"ext": len(req.ext_handles),
-                              "sparse": True})
-            return out
-        t0 = time.perf_counter()
-        counts = self.backend.sweep_many(self.arena, [req])[0]
-        with self._cv:
-            self.sweep_s += time.perf_counter() - t0
-        if self.cluster is not None:
-            counts = self.cluster.reduce_flush([req], [counts])[0]
-        if tr is not None:
-            tr.span("sweep", t0, cat="sweep",
-                    args={"ext": len(req.ext_handles), "sparse": False})
+            counts = self.backend.sweep_many(self.arena, [req])[0]
+            with self._cv:
+                self.sweep_s += time.perf_counter() - t0
+            if self.cluster is not None:
+                counts = self.cluster.reduce_flush([req], [counts])[0]
         return counts, None
 
     @property
@@ -814,20 +876,20 @@ class SweepDispatcher:
              "query_requests": self.query_requests,
              "queue_flushes": self.queue_flushes,
              "queue_requests": self.queue_requests,
+             "queue_wait_us": self.queue_wait_us,
+             **self.work,
              "sweep_s": self.sweep_s})
 
     def _flush_args(self, batch: Sequence[SweepRequest],
                     inline: bool = False) -> Dict[str, float]:
-        """Span payload for one flush: occupancy, an upper-bound byte
-        figure (rows × full arena width — segment-restricted sweeps
-        read less), and the dense/sparse representation split. Only
-        runs when a tracer is attached."""
+        """Span payload for one flush: requests, rows and the
+        dense/sparse representation split. Only runs when a tracer is
+        attached."""
         arena = self.arena
         rows = sum(len(r.prefix_handles) + len(r.ext_handles)
                    for r in batch)
         sparse = sum(1 for r in batch if r.is_sparse(arena))
-        return {"requests": len(batch), "occupancy": len(batch),
-                "rows": rows, "batch_bytes": rows * arena.n_words * 4,
+        return {"requests": len(batch), "rows": rows,
                 "sparse": sparse, "dense": len(batch) - sparse,
                 "queries": sum(1 for r in batch if r.priority),
                 "inline": inline}
@@ -839,27 +901,20 @@ class SweepDispatcher:
             tr.set_lane(f"dispatcher-{self.shard}",
                         sort_index=1000 + self.shard,
                         pid=self.trace_pid)
+        _dispatch_thread.work = self.work
+        _dispatch_thread.tracer = tr
         full = min(self.max_batch, self.n_clients)
         while True:
             with self._cv:
-                while not self._pending and not self._stop:
-                    self._cv.wait()
+                if not self._pending and not self._stop:
+                    with region(tr, "dispatch.idle", cat="idle"):
+                        while not self._pending and not self._stop:
+                            self._cv.wait()
                 if not self._pending and self._stop:
                     return
                 if len(self._pending) < full and not self._stop:
-                    deadline = time.monotonic() + self.flush_s
-                    while len(self._pending) < full and not self._stop:
-                        # a pending query caps the straggler wait: the
-                        # cap re-applies on every pass so a query that
-                        # ARRIVES mid-wait also shortens the window
-                        if self._n_priority:
-                            deadline = min(
-                                deadline,
-                                time.monotonic() + self.query_flush_s)
-                        left = deadline - time.monotonic()
-                        if left <= 0:
-                            break
-                        self._cv.wait(timeout=left)
+                    with region(tr, "dispatch.form", cat="idle"):
+                        self._wait_stragglers(full)
                 batch = self._pending[:self.max_batch]
                 del self._pending[:self.max_batch]
                 self._n_priority -= sum(1 for r in batch if r.priority)
@@ -867,27 +922,47 @@ class SweepDispatcher:
                 self.requests += len(batch)   # sweep_local's local bursts
                 self.queue_flushes += 1
                 self.queue_requests += len(batch)
+                t_start = time.perf_counter()
+                self.queue_wait_us += int(1e6 * sum(
+                    t_start - r.t_submit for r in batch))
             try:
-                t0 = time.perf_counter()
-                results = self.backend.sweep_many(self.arena, batch)
-                t1 = time.perf_counter()
-                with self._cv:
-                    self.sweep_s += t1 - t0
-                if self.cluster is not None:
-                    results = self.cluster.reduce_flush(batch, results)
-                    if tr is not None:
+                with region(tr, "flush", cat="flush") as args:
+                    t0 = time.perf_counter()
+                    results = self.backend.sweep_many(self.arena, batch)
+                    with self._cv:
+                        self.sweep_s += time.perf_counter() - t0
+                    if self.cluster is not None:
                         # the cross-host reduction tail of this flush
-                        tr.span("net-flush", t1, cat="net",
-                                args={"requests": len(batch)})
-                if tr is not None:
-                    tr.span("flush", t0, cat="flush",
-                            args=self._flush_args(batch))
+                        with region(tr, "net-flush", cat="net") as a:
+                            if a is not None:
+                                a["requests"] = len(batch)
+                            results = self.cluster.reduce_flush(
+                                batch, results)
+                    if args is not None:
+                        args.update(self._flush_args(batch))
             except BaseException as e:  # noqa: BLE001 - resolve futures:
                 for r in batch:         # a swallowed error would deadlock
                     r.future.set_exception(e)   # every blocked worker
             else:
                 for r, counts in zip(batch, results):
                     r.future.set_result(counts)
+
+    def _wait_stragglers(self, full: int) -> None:
+        """Hold the forming flush (caller holds ``_cv``) until ``full``
+        requests are pending, ``flush_us`` has passed, or a pending
+        query's shorter cap has."""
+        deadline = time.monotonic() + self.flush_s
+        while len(self._pending) < full and not self._stop:
+            # a pending query caps the straggler wait: the cap
+            # re-applies on every pass so a query that ARRIVES
+            # mid-wait also shortens the window
+            if self._n_priority:
+                deadline = min(deadline,
+                               time.monotonic() + self.query_flush_s)
+            left = deadline - time.monotonic()
+            if left <= 0:
+                break
+            self._cv.wait(timeout=left)
 
     def stop(self):
         """Drain pending requests, then join the dispatcher thread."""

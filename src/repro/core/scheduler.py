@@ -29,6 +29,7 @@ import zlib
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
+from repro.obs import region
 from repro.obs import schema as obs_schema
 
 
@@ -572,10 +573,15 @@ class TaskScheduler:
         task = self.policy.get(i)
         if task is not None:
             return task
+        with region(self.tracer, "steal", cat="steal") as args:
+            return self._steal(i, args)
+
+    def _steal(self, i: int, args: Optional[Dict[str, Any]]
+               ) -> Optional[Task]:
+        """Probe random victims, then a peer host; ``args`` (the steal
+        span's, None when untraced) records the outcome."""
         st = self.stats[i]
         rng = self._rngs[i]
-        tr = self.tracer
-        t_steal = tr.now() if tr is not None else 0.0
         for _ in range(4 * self.n):
             victim = rng.randrange(self.n)
             if victim == i:
@@ -599,10 +605,9 @@ class TaskScheduler:
                     for t in got[1:]:
                         self.policy.put(i, t)
                     self._signal_work()
-                if tr is not None:
-                    tr.span("steal", t_steal, cat="steal",
-                            args={"victim": victim, "tasks": len(got),
-                                  "migrated": src != dst, "hit": True})
+                if args is not None:
+                    args.update(victim=victim, tasks=len(got),
+                                migrated=src != dst, hit=True)
                 return got[0]
         # local queues and victims are all dry: escalate to a
         # cross-host steal if a cluster installed one. The callback
@@ -616,13 +621,11 @@ class TaskScheduler:
             if n > 0:
                 st.steals += 1
                 st.tasks_stolen += n
-                if tr is not None:
-                    tr.span("steal", t_steal, cat="steal",
-                            args={"remote": True, "tasks": n,
-                                  "hit": True})
+                if args is not None:
+                    args.update(remote=True, tasks=n, hit=True)
                 return self.policy.get(i)
-        if tr is not None:
-            tr.span("steal", t_steal, cat="steal", args={"hit": False})
+        if args is not None:
+            args["hit"] = False
         return None
 
     def _worker(self, i: int):
@@ -657,49 +660,47 @@ class TaskScheduler:
                 # no running task to spawn, so a fully idle scheduler
                 # parks untimed: a persistent serving runtime costs
                 # zero wakeups between refreshes.
-                t_park = tr.now() if tr is not None else 0.0
                 with self._cv:
                     if self._stop:
                         return
-                    self._parked += 1
-                    try:
-                        # with cluster hooks installed, "nothing
-                        # outstanding HERE" is not "nothing to do": a
-                        # peer host may have (or later GET) stealable
-                        # work, and no local put will ever wake us for
-                        # it — so cluster mode always keeps the timed
-                        # park. ~20 cheap probes/s per idle worker,
-                        # only while a cluster is attached.
-                        untimed = (self._outstanding == 0
-                                   and self._remote_work_cb is None)
-                        self._cv.wait_for(
-                            lambda: (self._stop
-                                     or self._work_seq != seen),
-                            timeout=(None if untimed else 0.05))
-                    finally:
-                        self._parked -= 1
-                if tr is not None:
-                    tr.span("park", t_park, cat="idle")
+                    with region(tr, "park", cat="idle"):
+                        self._parked += 1
+                        try:
+                            # with cluster hooks installed, "nothing
+                            # outstanding HERE" is not "nothing to do":
+                            # a peer host may have (or later GET)
+                            # stealable work, and no local put will
+                            # ever wake us for it — so cluster mode
+                            # always keeps the timed park. ~20 cheap
+                            # probes/s per idle worker, only while a
+                            # cluster is attached.
+                            untimed = (self._outstanding == 0
+                                       and self._remote_work_cb is None)
+                            self._cv.wait_for(
+                                lambda: (self._stop
+                                         or self._work_seq != seen),
+                                timeout=(None if untimed else 0.05))
+                        finally:
+                            self._parked -= 1
                 continue
-            t_task = tr.now() if tr is not None else 0.0
-            try:
-                task.result = task.fn(*task.args)
-            except BaseException as e:  # noqa: BLE001 - must not leak:
-                task.error = e          # a dead worker would deadlock
-                                        # wait_all (outstanding never 0)
-            finally:
-                task.args = ()      # drop arg refs even on error:
+            with region(tr, "task", cat="task") as args:
+                try:
+                    task.result = task.fn(*task.args)
+                except BaseException as e:  # noqa: BLE001 - must not
+                    task.error = e  # leak: a dead worker would deadlock
+                                    # wait_all (outstanding never 0)
+                finally:
+                    task.args = ()  # drop arg refs even on error:
                                     # parent-handed bitmaps must free
                                     # once consumed
-            if tr is not None:
-                attr = task.attr
-                args = {"depth": task.depth}
-                if isinstance(attr, tuple) and len(attr) == 2:
-                    args["bucket"] = attr[0]
-                    args["prefix"] = repr(attr[1])
-                elif attr is not None:
-                    args["prefix"] = repr(attr)
-                tr.span("task", t_task, cat="task", args=args)
+                if args is not None:
+                    attr = task.attr
+                    args["depth"] = task.depth
+                    if isinstance(attr, tuple) and len(attr) == 2:
+                        args["bucket"] = attr[0]
+                        args["prefix"] = repr(attr[1])
+                    elif attr is not None:
+                        args["prefix"] = repr(attr)
             st.tasks_run += 1
             with self._cv:
                 self._outstanding -= 1

@@ -403,7 +403,8 @@ class BitmapArena:
         self.compaction_bytes = 0     # host bytes repacked by compact()
         self.compactions = 0          # compact() calls that merged
         # observability: None = off (the engines attach a tracer;
-        # h2d/d2d/compaction then emit spans on the calling lane)
+        # mirror syncs, migrations and compactions then emit spans on
+        # the calling lane)
         self.tracer = None
         # hybrid sparse representation: per-slot tag plus a
         # variable-length tid/diffset store sharing the same handle
@@ -1120,18 +1121,12 @@ class BitmapArena:
         :meth:`device_rows`."""
         if self.n_shards == 1:
             return
-        tr = self.tracer
-        d2d0 = self.d2d_bytes if tr is not None else 0
         with self._lock:
             self._note_sparse(shard, handles)
             segs = (segments if segments is not None
                     else range(len(self._seg_words)))
             for g in segs:
                 self._sync_plan(shard, g, handles)
-        if tr is not None and self.d2d_bytes != d2d0:
-            tr.instant("d2d", cat="arena",
-                       args={"shard": shard,
-                             "bytes": self.d2d_bytes - d2d0})
 
     def _note_sparse(self, shard: int, handles: Sequence[int]) -> None:
         """Cross-shard residency billing for sparse rows (caller holds
@@ -1239,7 +1234,7 @@ class BitmapArena:
             dev = set_rows(dev, *map(_place, _pow2_set(fetch, fe_rows)))
         self._dev[shard][segment] = dev
         if h2d_delta:
-            self.count_h2d(h2d_delta, _traced=False)
+            self.count_h2d(h2d_delta)
             if tr is not None:
                 # only syncs that actually moved payload get a span —
                 # the steady-state no-op sync stays invisible
@@ -1248,15 +1243,12 @@ class BitmapArena:
                               "bytes": h2d_delta})
         return dev
 
-    def count_h2d(self, nbytes: int, _traced: bool = True) -> None:
+    def count_h2d(self, nbytes: int) -> None:
         """Backends add per-batch host→device payload here (the
         host-gather fallback path). Locked: with one dispatcher thread
         per shard, concurrent flushes update the shared gauge."""
         with self._lock:
             self.h2d_bytes += nbytes
-        if _traced and self.tracer is not None:
-            self.tracer.instant("h2d", cat="arena",
-                                args={"bytes": nbytes})
 
     def __repr__(self) -> str:   # pragma: no cover - debugging aid
         return (f"<BitmapArena rows={self.n_rows} base={self.n_base} "

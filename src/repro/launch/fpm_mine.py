@@ -16,6 +16,24 @@ from repro.core.fpm import (GRANULARITIES, mesh_over_devices, mine,
 from repro.core.tidlist import pack_database
 from repro.data.transactions import PROFILES, load, min_support_count
 from repro.obs import Tracer, summary_table, write_chrome_trace
+from repro.obs import schema as obs_schema
+
+
+def _dispatch_summary(per_device) -> str:
+    """The dispatchers' queue wait per queued request, and each
+    launched kernel's fill: logical over padded work, %."""
+    c = obs_schema.merge_counters(per_device, obs_schema.DEVICE_COUNTERS)
+    out = []
+    if c["queue_requests"]:
+        out.append(f"queue_wait="
+                   f"{c['queue_wait_us'] / c['queue_requests'] / 1e3:.3f}ms")
+    for kernel, unit in (("bitmap_join", "words"),
+                         ("gather_intersect", "probes")):
+        padded = c[f"{kernel}_padded_{unit}"]
+        if padded:
+            out.append(f"{kernel}_fill="
+                       f"{100 * c[f'{kernel}_{unit}'] / padded:.1f}%")
+    return " ".join(out)
 
 
 def _finish_trace(args, tracer, wall_s: float) -> None:
@@ -300,7 +318,8 @@ def main():
                 f"bucket_switches={int(s['bucket_switches']):5d}")
         if met.flushes:
             line += (f" batch_occ={met.batch_occupancy:4.2f} "
-                     f"flushes={met.flushes} h2d={met.h2d_bytes}B")
+                     f"flushes={met.flushes} h2d={met.h2d_bytes}B "
+                     f"{_dispatch_summary(met.per_device)}")
         if met.n_devices > 1:
             occ = "/".join(f"{d['batch_occupancy']:.2f}"
                            for d in met.per_device)

@@ -9,8 +9,12 @@ Usage (batch mining)::
     print(summary_table(tr, wall_s=met.wall_s))
 
 Tracing is off by default: every instrumented site holds a tracer
-reference that is ``None`` unless the caller passed one, so the
-disabled fast path is a single ``is not None`` test. See
+reference that is ``None`` unless the caller passed one and opens
+``region(tracer, name)``, so the disabled fast path is a single ``is
+None`` test and a shared null context. Every region of a live tracer
+is also a ``repro:<name>`` ``jax.profiler`` annotation, so under
+``jax.profiler.trace`` the program's spans share the profiler's clock
+with the device operations. See
 ``repro.obs.tracer`` for the ring-buffer design, ``repro.obs.schema``
 for the unified merged-stats schema, ``repro.obs.registry`` for the
 pull-based metrics snapshot API.
@@ -20,11 +24,11 @@ from repro.obs.export import (  # noqa: F401
     write_chrome_trace,
 )
 from repro.obs.registry import LatencyRecorder, MetricsRegistry  # noqa: F401
-from repro.obs.tracer import TraceEvent, Tracer  # noqa: F401
+from repro.obs.tracer import TraceEvent, Tracer, region  # noqa: F401
 from repro.obs import schema  # noqa: F401
 
 __all__ = [
-    "Tracer", "TraceEvent", "chrome_trace", "write_chrome_trace",
+    "Tracer", "TraceEvent", "region", "chrome_trace", "write_chrome_trace",
     "summary_table", "time_in_state", "check_nesting",
     "MetricsRegistry", "LatencyRecorder", "schema",
 ]

@@ -22,7 +22,8 @@ from typing import Any, Dict, Mapping, Tuple
 
 __all__ = [
     "SCHEDULER_COUNTERS", "SCHEDULER_DERIVED",
-    "DEVICE_ID_KEYS", "DEVICE_COUNTERS", "DEVICE_DERIVED",
+    "DEVICE_ID_KEYS", "KERNEL_COUNTERS", "DEVICE_COUNTERS",
+    "DEVICE_DERIVED",
     "QUERY_COUNTERS", "QUERY_DERIVED",
     "HOST_ID_KEYS", "HOST_COUNTERS", "HOST_DERIVED",
     "scheduler_stats", "device_stats", "query_stats", "host_stats",
@@ -40,10 +41,21 @@ SCHEDULER_DERIVED: Tuple[str, ...] = ("tasks_per_steal",)
 
 # ---- per-device: dispatcher gauges / MiningMetrics.per_device rows --
 DEVICE_ID_KEYS: Tuple[str, ...] = ("device",)      # +"host" in cluster rows
+# per kernel: launches, the logical work of the requests launched
+# (dense: words read, Σ (L + E) × W; sparse: bit probes, Σ S × E), and
+# the padded work actually launched (B' × (L' + E') × W', B' × S' × E')
+KERNEL_COUNTERS: Tuple[str, ...] = (
+    "bitmap_join_launches", "bitmap_join_words",
+    "bitmap_join_padded_words",
+    "gather_intersect_launches", "gather_intersect_probes",
+    "gather_intersect_padded_probes",
+)
+# queue_wait_us: µs from submit to the start of the carrying flush,
+# summed over the dispatcher queue's requests
 DEVICE_COUNTERS: Tuple[str, ...] = (
     "flushes", "sweep_requests", "query_requests", "queue_flushes",
-    "queue_requests",
-)
+    "queue_requests", "queue_wait_us",
+) + KERNEL_COUNTERS
 DEVICE_DERIVED: Tuple[str, ...] = ("batch_occupancy", "sweep_s")
 
 # ---- serving: PatternServer.merged_stats / TenantHub.tenant_stats --

@@ -9,18 +9,30 @@ When a ring fills, new events overwrite the oldest (drop-oldest); the
 ``dropped`` counter keeps the loss honest.
 
 The disabled fast path is structural, not a flag check inside the
-tracer: instrumentation sites hold ``tracer = None`` and guard with
-``if tr is not None`` — one local load and an identity test, so an
-untraced run pays nothing per event. A constructed ``Tracer`` is
-always live.
+tracer: instrumentation sites hold ``tracer = None`` and open
+``region(tracer, name)``, which is one ``is None`` test returning a
+shared null context — an untraced run builds no ring, no event and no
+profiler annotation. A constructed ``Tracer`` is always live.
 
 Spans are recorded as *complete* events at span end (Chrome trace
-``ph="X"``): the site captures ``t0 = tracer.now()`` before the work
-and calls ``tracer.span(name, t0)`` after, which stamps the duration.
-That makes one ring append per span and means per-lane append order is
-span *end* order — sorting by start time (ties: longer first)
-reconstructs the nesting, which is how the exporter's time-in-state
-accounting works.
+``ph="X"``). The usual form is a region::
+
+    with region(tr, "flush", cat="flush") as args:
+        ...                      # args: a dict (None when tr is None)
+
+which stamps start and end around the block. ``tracer.span(name, t0)``
+records a span whose start ``t0 = tracer.now()`` was taken on another
+code path, or whose recording is decided only at its end. One ring
+append per span means per-lane append order is span *end* order —
+sorting by start time (ties: longer first) reconstructs the nesting,
+which is how the exporter's time-in-state accounting works.
+
+Every region also opens a ``jax.profiler.TraceAnnotation`` named
+``"repro:" + name`` on the same thread: under ``jax.profiler.trace``
+the program's spans land in the profile's host plane on the profiler's
+own clock, beside the device operations (one Perfetto/TensorBoard view
+of host steps and device work). With no profiler session active the
+annotation records nothing.
 
 Lanes map onto Chrome trace (pid, tid): ``pid`` is the host rank
 (cluster mode gives every host its own process row in Perfetto) and
@@ -34,7 +46,10 @@ import threading
 import time
 from typing import Any, Dict, Iterable, List, NamedTuple, Optional, Tuple
 
-__all__ = ["Tracer", "TraceEvent"]
+__all__ = ["Tracer", "TraceEvent", "region"]
+
+# prefix of the profiler annotation every region opens
+PROFILER_PREFIX = "repro:"
 
 
 class TraceEvent(NamedTuple):
@@ -42,7 +57,7 @@ class TraceEvent(NamedTuple):
 
     ``ts``/``dur`` are seconds relative to the tracer epoch; the
     Chrome exporter converts to µs. ``ph`` follows the trace-event
-    format: "X" complete span, "I" instant, "C" counter.
+    format: "X" complete span, "C" counter.
     """
 
     ph: str
@@ -83,18 +98,74 @@ class _Ring:
         return max(0, self.n - self.cap)
 
     def snapshot(self) -> List[tuple]:
-        """Events in append order, oldest first (last ``cap`` kept)."""
-        if self.n <= self.cap:
-            return [e for e in self.buf[: self.idx] if e is not None]
+        """Events in append order, oldest first (last ``cap`` kept).
+        The oldest event sits at the write slot once the ring has
+        wrapped; before that the slots from it on are still empty."""
         i = self.idx
         return [e for e in self.buf[i:] + self.buf[:i] if e is not None]
 
 
-class Tracer:
-    """Collects span/instant/counter events into per-thread rings.
+class _Region:
+    """One span around a ``with`` block (see ``region``)."""
 
-    Record methods (``span``/``instant``/``counter``) are safe from any
-    thread and lock-free after the thread's first event. Collection
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0", "_ann")
+
+    def __init__(self, tr: "Tracer", name: str, cat: str,
+                 args: Optional[Dict[str, Any]]):
+        self._tr = tr
+        self._name = name
+        self._cat = cat
+        self._args = {} if args is None else args
+        self._ann = None
+
+    def __enter__(self) -> Dict[str, Any]:
+        self._ann = self._tr._annotation(PROFILER_PREFIX + self._name)
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self._args
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        tr = self._tr
+        tr._ring().append(("X", self._name, self._cat, self._t0 - tr._epoch,
+                           t1 - self._t0, self._args or None))
+        self._ann.__exit__(None, None, None)
+        return False
+
+
+class _NullRegion:
+    """The shared region of an untraced site: records nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+NULL_REGION = _NullRegion()
+
+
+def region(tracer: Optional["Tracer"], name: str, cat: str = "span",
+           args: Optional[Dict[str, Any]] = None):
+    """A context manager recording one complete span around its block
+    on the calling thread's ring, inside the profiler annotation
+    ``"repro:" + name``; ``as`` binds the span's args dict (``args``,
+    or a fresh one), which the block may fill. When ``tracer`` is None
+    (tracing off) it is the shared null region, whose ``as`` value is
+    None, so a site fills args only under ``if a is not None``."""
+    if tracer is None:
+        return NULL_REGION
+    return _Region(tracer, name, cat, args)
+
+
+class Tracer:
+    """Collects span and counter events into per-thread rings.
+
+    Record paths (``region(tracer, ...)``/``span``/``counter``) are
+    safe from any thread and lock-free after the thread's first event. Collection
     (``events()``/``rings()``) merges all rings preserving each lane's
     internal order; it is meant to run at quiescence (after
     ``mine()``/``refresh()`` returns) but tolerates concurrent writers
@@ -105,7 +176,9 @@ class Tracer:
     def __init__(self, ring_size: int = 65536):
         if ring_size < 8:
             raise ValueError("ring_size must be >= 8")
+        from jax.profiler import TraceAnnotation
         self.ring_size = int(ring_size)
+        self._annotation = TraceAnnotation
         self._epoch = time.perf_counter()
         self._tls = threading.local()
         self._lock = threading.Lock()
@@ -145,14 +218,11 @@ class Tracer:
 
     def span(self, name: str, t0: float, cat: str = "span",
              args: Optional[Dict[str, Any]] = None) -> None:
-        """Record a complete span that started at ``t0 = tracer.now()``."""
+        """Record a complete span that started at ``t0 = tracer.now()``
+        — for a start and end on different code paths, or a span kept
+        only if its end decides so. Not mirrored into the profiler."""
         t1 = time.perf_counter()
         self._ring().append(("X", name, cat, t0 - self._epoch, t1 - t0, args))
-
-    def instant(self, name: str, cat: str = "event",
-                args: Optional[Dict[str, Any]] = None) -> None:
-        ts = time.perf_counter() - self._epoch
-        self._ring().append(("I", name, cat, ts, 0.0, args))
 
     def counter(self, name: str, values: Dict[str, Any]) -> None:
         """Record a counter sample (Perfetto draws these as tracks)."""

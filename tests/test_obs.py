@@ -9,7 +9,7 @@ from repro.core.fpm import mine
 from repro.core.tidlist import pack_database
 from repro.data.transactions import load
 from repro.obs import (LatencyRecorder, MetricsRegistry, Tracer,
-                       check_nesting, chrome_trace, schema,
+                       check_nesting, chrome_trace, region, schema,
                        summary_table, time_in_state, write_chrome_trace)
 
 
@@ -46,6 +46,63 @@ def test_ring_overflow_drops_oldest_without_corruption():
     assert all(e.dur == 0.5 for e in evs)
     assert tr.dropped() == 12
     assert "dropped" in str(chrome_trace(tr).get("otherData", {}))
+
+
+@pytest.mark.parametrize("n", [7, 8, 9])
+def test_ring_at_and_around_capacity_keeps_the_newest(n):
+    # n == ring_size leaves the write slot wrapped to 0 with nothing
+    # dropped: the whole ring, oldest first
+    tr = Tracer(ring_size=8)
+    for i in range(n):
+        _span(tr, f"s{i}", float(i), 0.5)
+    assert [e.name for e in tr.events()] == \
+        [f"s{i}" for i in range(max(0, n - 8), n)]
+    assert tr.dropped() == max(0, n - 8)
+
+
+def test_region_records_one_span_with_the_args_it_was_given():
+    tr = Tracer()
+    with region(tr, "work", cat="task") as args:
+        args["k"] = 1
+    with region(tr, "empty"):
+        pass
+    with pytest.raises(ValueError):
+        with region(tr, "raised", cat="task"):
+            raise ValueError("x")
+    evs = tr.events()
+    assert [(e.ph, e.name, e.cat) for e in evs] == [
+        ("X", "work", "task"), ("X", "empty", "span"),
+        ("X", "raised", "task")]
+    assert evs[0].args == {"k": 1} and evs[1].args is None
+    assert all(e.dur >= 0.0 for e in evs)
+
+
+def test_region_without_a_tracer_is_one_shared_null_context():
+    a, b = region(None, "x"), region(None, "y", cat="task")
+    assert a is b
+    with a as args:
+        assert args is None
+
+
+def test_tracer_writes_its_regions_into_the_xplane(small_db, tmp_path):
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+    db, p = small_db
+    bm, counts = pack_database(db, p.n_dense_items, return_counts=True)
+    tr = Tracer()
+    with jax.profiler.trace(str(tmp_path)):
+        mine(bm, int(0.3 * len(db)), n_workers=2, max_k=3,
+             item_counts=counts, trace=tr)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    names = {ev.name for plane in ProfileData.from_file(path).planes
+             for line in plane.lines for ev in line.events}
+    assert {"repro:flush", "repro:level.plan", "repro:level.barrier",
+            "repro:mine.start", "repro:task"} <= names
+    assert {e.name for e in tr.events()} >= {
+        n[len("repro:"):] for n in names if n.startswith("repro:")}
 
 
 def test_ring_is_per_thread_and_lane_order_is_stable():
@@ -280,6 +337,70 @@ def test_traced_mine_matches_untraced_and_covers_workers(small_db):
                        if e.get("cat") == "task"}
     assert len(lanes_with_tasks) >= 4
     json.dumps(doc)                                # serializable
+
+
+DRIVER_SPANS = {"mine.arena", "mine.level1", "mine.start", "mine.close",
+                "mine.finalize", "level.candidates", "level.plan",
+                "level.spawn", "level.barrier", "level.collect"}
+DISPATCHER_SPANS = {"dispatch.idle", "dispatch.form", "flush",
+                    "flush.prepare", "flush.launch", "flush.wait"}
+
+
+@pytest.mark.parametrize("representation,support",
+                         [("bitmap", 0.3), ("sparse", 0.2)])
+def test_traced_kernel_mine_splits_driver_and_flush(small_db,
+                                                    representation,
+                                                    support):
+    """A kernel-backend mine: the driver lane tiles the call into its
+    steps, every flush holds one prepare/launch/wait triple per kernel
+    launch, and each kernel's logical work is at most its padded
+    work (the sparse case reaches level 3, whose cached prefixes are
+    tid-lists swept by gather_intersect)."""
+    db, p = small_db
+    bm, counts = pack_database(db, p.n_dense_items, return_counts=True)
+    ms = int(support * len(db))
+    tr = Tracer()
+    res, met = mine(bm, ms, n_workers=2, max_k=4,
+                    backend="pallas-interpret", item_counts=counts,
+                    representation=representation, trace=tr)
+    ref, _ = mine(bm, ms, n_workers=2, max_k=4, item_counts=counts)
+    assert res == ref
+    evs = tr.events()
+    assert check_nesting(evs) == []
+    spans = [e for e in evs if e.ph == "X"]
+    by_lane = {}
+    for e in spans:
+        by_lane.setdefault(e.lane, set()).add(e.name)
+    assert DRIVER_SPANS <= by_lane["driver"]
+    assert DISPATCHER_SPANS <= by_lane["dispatcher-0"]
+    launches = [e for e in spans if e.name == "flush.launch"]
+    flushes = [e for e in spans if e.name == "flush"]
+    for name in ("flush.prepare", "flush.wait"):
+        assert sum(e.name == name for e in spans) == len(launches)
+    assert len(launches) >= len(flushes)
+    row = met.per_device[0]
+    schema.validate("device", row)
+    n_launch = row["bitmap_join_launches"] + row["gather_intersect_launches"]
+    assert n_launch == len(launches)
+    kernels = {e.args["kernel"] for e in launches}
+    if representation == "sparse":
+        assert "gather_intersect" in kernels
+    assert 0 < row["bitmap_join_words"] <= row["bitmap_join_padded_words"]
+    assert row["gather_intersect_probes"] <= \
+        row["gather_intersect_padded_probes"]
+    assert (row["gather_intersect_probes"] > 0) == \
+        ("gather_intersect" in kernels)
+    assert row["queue_requests"] == row["sweep_requests"] > 0
+    assert row["queue_wait_us"] > 0
+    # the driver's top-level spans tile the call; a level-k span only
+    # for a level that had candidates
+    top = [e for e in spans if e.lane == "driver"
+           and (e.name.startswith("mine.") or e.name.startswith("level-")
+                or e.name == "level.candidates")]
+    assert sum(e.name.startswith("level-") for e in top) == met.levels
+    first = min(e.ts for e in top)
+    last = max(e.ts + e.dur for e in top)
+    assert sum(e.dur for e in top) >= 0.95 * (last - first)
 
 
 def test_traced_streaming_spans_lag_and_latency(small_db):
