@@ -1,11 +1,13 @@
-"""Prefix-bucket planning + the shared rows-touched cost model.
+"""Prefix-bucket cost models: rows touched, bytes, density.
 
 The paper's clustered policy groups level-k candidate tasks by their
-(k-1)-prefix (§4). ``repro.core.fpm`` makes the *bucket* the unit of
-task execution (prefix intersection computed once, extensions swept
-vectorized) — and since the engine went mesh-aware, bucket placement
-on workers IS bucket placement on devices, so this grouping also
-defines what a cross-device bucket steal migrates.
+(k-1)-prefix (§4); :func:`repro.core.itemsets.gen_buckets` plans a
+level in that form directly, and ``repro.core.fpm`` makes the
+*bucket* the unit of task execution (prefix intersection computed
+once, extensions swept vectorized) — and since the engine went
+mesh-aware, bucket placement on workers IS bucket placement on
+devices, so this grouping also defines what a cross-device bucket
+steal migrates.
 
 Cost model: the engine MEASURES rows-touched per task (cache hits
 reduce it) and converts via :func:`rows_to_bytes`;
@@ -18,41 +20,8 @@ read against (and pinned by tests), not called on the hot path.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Sequence, Tuple
-
-from repro.core.itemsets import Itemset, prefix_hash
 
 BYTES_PER_WORD = 4                    # uint32 TID-bitmap words
-
-
-@dataclasses.dataclass(frozen=True)
-class Bucket:
-    """All level-k candidates sharing one (k-1)-prefix.
-
-    ``key`` is the paper's XOR'd prefix hash (the clustered policy's
-    bucket key); ``exts`` are the candidates' last items, sorted, so the
-    bucket's candidate set is ``{prefix + (e,) for e in exts}``.
-    """
-    key: int
-    prefix: Itemset
-    exts: Tuple[int, ...]
-
-    def __len__(self) -> int:
-        return len(self.exts)
-
-    def candidates(self) -> List[Itemset]:
-        return [self.prefix + (e,) for e in self.exts]
-
-
-def group_by_prefix(cands: Sequence[Itemset]) -> List[Bucket]:
-    """Group candidates by (k-1)-prefix, preserving first-seen prefix
-    order (Apriori's gen_candidates emits prefixes contiguously, so this
-    is also prefix-sorted order for sorted inputs)."""
-    groups: Dict[Tuple[int, Itemset], List[int]] = {}
-    for c in cands:
-        groups.setdefault((prefix_hash(c), c[:-1]), []).append(c[-1])
-    return [Bucket(h, pref, tuple(sorted(ext)))
-            for (h, pref), ext in groups.items()]
 
 
 def bucket_rows_touched(prefix_len: int, n_exts: int) -> int:

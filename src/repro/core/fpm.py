@@ -61,11 +61,10 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core import tidlist
-from repro.core.buckets import (REPRESENTATIONS, Bucket, DensityModel,
-                                class_rows_touched, group_by_prefix,
-                                rows_to_bytes)
-from repro.core.itemsets import (Itemset, gen_candidates, itemset_hash,
-                                 prefix_hash)
+from repro.core.buckets import (REPRESENTATIONS, DensityModel,
+                                class_rows_touched, rows_to_bytes)
+from repro.core.itemsets import (Bucket, Itemset, gen_buckets,
+                                 gen_candidates, itemset_hash)
 from repro.core.join_backend import (FLUSH_US, MAX_BATCH, SweepDispatcher,
                                      resolve_backend)
 from repro.core.scheduler import TaskScheduler, make_policy
@@ -354,33 +353,36 @@ class DeltaPlan:
 
     def classify_buckets(self, plan: List[Bucket]
                          ) -> Tuple[List[Tuple[Itemset, int]],
-                                    List[Bucket], List[Itemset]]:
+                                    List[Bucket], List[Bucket]]:
         """Split a level's prefix buckets into (clean ``(c, support)``
-        pairs, dirty sub-buckets, fresh candidates) in one pass over
+        pairs, dirty sub-buckets, fresh sub-buckets) in one pass over
         the already-grouped plan. The prefix's dirtiness is probed
         ONCE per bucket — the per-candidate hot loop is one
         ``known.get`` plus one set probe for the extension item, and
-        dirty extensions stay bucketed so the delta path never
-        re-groups them."""
+        dirty and fresh extensions stay bucketed so the delta path
+        never re-groups them."""
         known, ditems = self.known, self.dirty_items
         clean: List[Tuple[Itemset, int]] = []
         dirty: List[Bucket] = []
-        fresh: List[Itemset] = []
+        fresh: List[Bucket] = []
         for b in plan:
             p = b.prefix
             p_dirty = all(i in ditems for i in p)
             d_exts: List[int] = []
+            f_exts: List[int] = []
             for e in b.exts:
                 c = p + (e,)
                 ks = known.get(c)
                 if ks is None:
-                    fresh.append(c)
+                    f_exts.append(e)
                 elif p_dirty and e in ditems:
                     d_exts.append(e)
                 else:
                     clean.append((c, ks))
             if d_exts:
                 dirty.append(Bucket(b.key, p, tuple(d_exts)))
+            if f_exts:
+                fresh.append(Bucket(b.key, p, tuple(f_exts)))
         return clean, dirty, fresh
 
 
@@ -441,8 +443,12 @@ class EngineRuntime:
         # pull-based snapshot API: live gauges, readable any time
         self.registry = MetricsRegistry()
         self.registry.register("scheduler", self.sched.merged_stats)
+        # the gauges close over locals, never ``self``: a cycle through
+        # the runtime would keep a finished mine's arena, and its device
+        # mirror, alive until the cyclic collector happened to run
+        dispatchers = self.dispatchers
         self.registry.register(
-            "per_device", lambda: [d.stats() for d in self.dispatchers])
+            "per_device", lambda: [d.stats() for d in dispatchers])
         self.registry.register(
             "arena", lambda: {"h2d_bytes": store.h2d_bytes,
                               "d2d_bytes": store.d2d_bytes,
@@ -724,6 +730,11 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
                     cluster=None):
     """Level-synchronous engines: plan level k, spawn, barrier, plan
     level k+1 (the paper's §2 shape, at candidate or bucket grain).
+    The planner is ``gen_buckets``: a level stays in prefix-bucket form
+    from candidate generation to thresholding (candidate grain
+    flattens it only to spawn), and a plain mine's collect pairs up
+    only the frequent extensions, so no per-candidate tuple is built
+    on the driver's serial path.
     ``sweep_joins`` routes even candidate-granularity scalar joins
     through the (per-device) dispatchers — multi-shard runs need every
     row access on the owning shard's path for d2d accounting;
@@ -748,7 +759,7 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
     sparse (or thin enough that level barriers dominate), the whole
     bucket detaches into a depth-first class task — the subtree mines
     barrier-free in the model-picked representation and its itemsets
-    never re-enter the level frontier (``gen_candidates`` gets the
+    never re-enter the level frontier (``gen_buckets`` gets the
     full known-frequent set so cross-prefix pruning stays exact).
     Under a delta plan auto stays level-synchronous: the classify
     clean/dirty/fresh split already skips clean work, and diffset
@@ -866,9 +877,8 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
         df_miner.class_task(bucket.prefix, ph, bucket.exts, psup,
                             own_support, True)
 
-    def _spawn_buckets(cands, segments):
+    def _spawn_buckets(plan: List[Bucket], segments):
         with region(tr, "level.plan", cat="level"):
-            plan = group_by_prefix(cands)
             detach = []
             if df_miner is not None:
                 keep = []
@@ -903,14 +913,19 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
                      for b in plan]
         return plan, tasks
 
-    def _spawn_candidates(cands, segments):
+    def _spawn_candidates(plan: List[Bucket], segments):
         prio = delta.priority_of if delta is not None else None
         tenant = delta.tenant if delta is not None else None
-        return [sched.spawn(count_task, c, segments,
-                            attr=(prefix_hash(c), c),
-                            priority=prio(c[:-1]) if prio else 0.0,
-                            tenant=tenant)
-                for c in cands]
+        cands, tasks = [], []
+        for b in plan:
+            priority = prio(b.prefix) if prio else 0.0
+            for c in b.candidates():
+                cands.append(c)
+                tasks.append(sched.spawn(count_task, c, segments,
+                                         attr=(b.key, c),
+                                         priority=priority,
+                                         tenant=tenant))
+        return cands, tasks
 
     def delta_chunk_task(chunk: List[Bucket]
                          ) -> List[Tuple[Itemset, int]]:
@@ -958,31 +973,43 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
             return [pair for t in tasks for pair in t.result]
         return collect
 
-    def _spawn_sweeps(cands, segments) -> Callable[
+    def _spawn_sweeps(plan: List[Bucket], segments) -> Callable[
             [], List[Tuple[Itemset, int]]]:
-        """Spawn sweeps for ``cands`` (bucket- or candidate-grained)
+        """Spawn sweeps for ``plan`` (bucket- or candidate-grained)
         and return a collector to call AFTER ``wait_all`` — fresh and
         dirty sweep sets share one level barrier. The collected counts
         cover ``segments`` only when restricted (the caller adds them
-        to the known supports)."""
+        to the known supports). A plain mine (no delta, no cluster)
+        thresholds each bucket's counts here and pairs up only the
+        frequent extensions; a refresh needs every swept support for
+        ``delta.known`` and a cluster every pair for its exchange."""
         if cluster is not None:
             # task partition: every host plans the SAME global frontier
             # but sweeps only its owned prefixes; the level exchange
             # merges the counted pairs back so thresholds stay global
-            cands = [c for c in cands if cluster.owns(c[:-1])]
-        if not cands:
+            plan = [b for b in plan if cluster.owns(b.prefix)]
+        if not plan:
             return lambda: []
         if granularity in ("bucket", "auto"):
-            plan, tasks = _spawn_buckets(cands, segments)
+            plan, tasks = _spawn_buckets(plan, segments)
 
             def collect():
                 _raise_task_errors(tasks)
-                return [(b.prefix + (e,), int(s))
-                        for b, t in zip(plan, tasks)
-                        for e, s in zip(b.exts, t.result)]
+                if delta is not None or cluster is not None:
+                    return [(b.prefix + (e,), int(s))
+                            for b, t in zip(plan, tasks)
+                            for e, s in zip(b.exts, t.result)]
+                out = []
+                for b, t in zip(plan, tasks):
+                    counts = t.result
+                    for i in np.flatnonzero(
+                            counts >= min_support).tolist():
+                        out.append((b.prefix + (b.exts[i],),
+                                    int(counts[i])))
+                return out
         else:
             with region(tr, "level.spawn", cat="level"):
-                tasks = _spawn_candidates(cands, segments)
+                cands, tasks = _spawn_candidates(plan, segments)
 
             def collect():
                 _raise_task_errors(tasks)
@@ -992,8 +1019,9 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
 
     def _keep_frequent(level: List[Tuple[Itemset, int]]
                        ) -> List[Itemset]:
-        """Threshold one level's counted candidates into ``result``;
-        the level's frequent itemsets, sorted."""
+        """Threshold one level's counted candidates (or, on a plain
+        mine, its already-thresholded pairs) into ``result``; the
+        level's frequent itemsets, sorted."""
         frequent = []
         for c, s in level:
             if s >= min_support:
@@ -1020,16 +1048,18 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
             # the Apriori prune needs the full known-frequent membership
             # (the result dict is complete here: the level barrier
             # below also waited on every detached class task)
-            cands = (gen_candidates(frequent, known_frequent=result)
-                     if df_miner is not None else gen_candidates(frequent))
-        if not cands:
+            plan = (gen_buckets(frequent, known_frequent=result)
+                    if df_miner is not None else gen_buckets(frequent))
+        if not plan:
             break
         with region(tr, f"level-{k}", cat="level") as level_args:
+            n_cands = sum(len(b.exts) for b in plan)
+            buckets_before = metrics.buckets
             metrics.levels += 1
-            metrics.candidates += len(cands)
+            metrics.candidates += n_cands
             level: List[Tuple[Itemset, int]] = []
             if delta is None:
-                collect = _spawn_sweeps(cands, None)
+                collect = _spawn_sweeps(plan, None)
                 _level_wait()
                 with region(tr, "level.collect", cat="level"):
                     if df_miner is not None:
@@ -1041,14 +1071,14 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
                     frequent = _keep_frequent(level)
             else:
                 with region(tr, "level.plan", cat="level"):
-                    clean, dirty, fresh = delta.classify_buckets(
-                        group_by_prefix(cands))
+                    clean, dirty, fresh = delta.classify_buckets(plan)
                     level.extend(clean)         # clean: zero rows read
                     if cluster is None or cluster.host_id == 0:
                         # a loopback cluster SHARES the plan: bill its
                         # avoided-work counters once, not once per host
                         delta.reused += len(clean)
-                        delta.swept_full += len(fresh)
+                        delta.swept_full += sum(len(b.exts)
+                                                for b in fresh)
                         delta.swept_delta += sum(len(b.exts)
                                                  for b in dirty)
                     if cluster is not None:
@@ -1092,7 +1122,11 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
                                                       update=_apply))
                     frequent = _keep_frequent(level)
             if level_args is not None:
-                level_args.update(candidates=len(cands),
+                # materialized: (itemset, support) pairs the level
+                # built — the frequent ones alone on a plain mine
+                level_args.update(candidates=n_cands,
+                                  buckets=metrics.buckets - buckets_before,
+                                  materialized=len(level),
                                   frequent=len(frequent))
         k += 1
 

@@ -8,6 +8,7 @@ combiner).
 """
 from __future__ import annotations
 
+import dataclasses
 from typing import Dict, Iterable, List, Sequence, Tuple
 
 Itemset = Tuple[int, ...]
@@ -37,21 +38,37 @@ def prefix_hash(itemset: Itemset) -> int:
     return itemset_hash(itemset[:-1])
 
 
-def prefix_of(itemset: Itemset) -> Itemset:
-    return itemset[:-1]
+@dataclasses.dataclass(frozen=True)
+class Bucket:
+    """All level-k candidates sharing one (k-1)-prefix.
+
+    ``key`` is the paper's XOR'd prefix hash (the clustered policy's
+    bucket key); ``exts`` are the candidates' last items, sorted, so the
+    bucket's candidate set is ``{prefix + (e,) for e in exts}``.
+    """
+    key: int
+    prefix: Itemset
+    exts: Tuple[int, ...]
+
+    def __len__(self) -> int:
+        return len(self.exts)
+
+    def candidates(self) -> List[Itemset]:
+        return [self.prefix + (e,) for e in self.exts]
 
 
-def gen_candidates(frequent: Sequence[Itemset],
-                   known_frequent: Iterable[Itemset] = ()) -> List[Itemset]:
-    """F_{k-1} -> C_k by prefix join + anti-monotone prune (Apriori).
+def gen_buckets(frequent: Sequence[Itemset],
+                known_frequent: Iterable[Itemset] = ()) -> List[Bucket]:
+    """F_{k-1} -> C_k by prefix join + anti-monotone prune (Apriori),
+    emitted already grouped by (k-1)-prefix: one bucket per joined
+    ``pref + (a,)`` whose extensions survive the prune, in the frontier's
+    first-seen (k-2)-prefix order, then ``a`` ascending.
 
     ``known_frequent`` widens the prune set beyond the join frontier:
     granularity="auto" detaches whole subtrees to depth-first class
     tasks, so their itemsets never re-enter ``frequent`` — without the
     full known-frequent membership, a candidate whose (k-1)-subset was
     mined inside a detached subtree would be falsely pruned."""
-    fset = set(frequent)
-    fset.update(known_frequent)
     if not frequent:
         return []
     k = len(frequent[0]) + 1
@@ -59,18 +76,38 @@ def gen_candidates(frequent: Sequence[Itemset],
     by_prefix: Dict[Itemset, List[int]] = {}
     for it in frequent:
         by_prefix.setdefault(it[:-1], []).append(it[-1])
-    out: List[Itemset] = []
+    if k > 2:
+        # the prune's lookup: (k-2)-prefix -> last items of every known
+        # (k-1)-itemset under it
+        lasts_of: Dict[Itemset, set] = {p: set(ls)
+                                        for p, ls in by_prefix.items()}
+        for it in known_frequent:
+            if len(it) == k - 1:
+                lasts_of.setdefault(it[:-1], set()).add(it[-1])
+    out: List[Bucket] = []
     for pref, lasts in by_prefix.items():
         lasts.sort()
+        h = itemset_hash(pref)
         for i, a in enumerate(lasts):
-            for b in lasts[i + 1:]:
-                cand = pref + (a, b)
-                # prune: every (k-1)-subset must be frequent
-                if k <= 2 or all(
-                        cand[:j] + cand[j + 1:] in fset
-                        for j in range(k)):
-                    out.append(cand)
+            exts = lasts[i + 1:]
+            # pref + (a,) and pref + (b,) are in the frontier; each other
+            # (k-1)-subset of pref + (a, b) drops one item of pref
+            for j in range(k - 2):
+                if not exts:
+                    break
+                known = lasts_of.get(pref[:j] + pref[j + 1:] + (a,), ())
+                exts = [b for b in exts if b in known]
+            if exts:
+                # the key is itemset_hash(pref + (a,)): XOR combines
+                out.append(Bucket(h ^ _mix(a), pref + (a,), tuple(exts)))
     return out
+
+
+def gen_candidates(frequent: Sequence[Itemset],
+                   known_frequent: Iterable[Itemset] = ()) -> List[Itemset]:
+    """:func:`gen_buckets`, flattened to one itemset per candidate."""
+    return [c for b in gen_buckets(frequent, known_frequent)
+            for c in b.candidates()]
 
 
 def brute_force_frequent(db: Sequence[Sequence[int]], min_support: int,

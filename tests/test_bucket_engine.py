@@ -1,16 +1,17 @@
 """Bucket-sweep engine: planning, equivalence across policies ×
 granularities × datasets, locality accounting, property tests."""
+from itertools import combinations
+
 import numpy as np
 import pytest
 from _hyp import given, settings, st
 
 from repro.core import tidlist
 from repro.core.buckets import (bucket_rows_touched,
-                                candidate_rows_touched, group_by_prefix,
-                                rows_to_bytes)
+                                candidate_rows_touched, rows_to_bytes)
 from repro.core.fpm import mine, mine_serial
-from repro.core.itemsets import (brute_force_frequent, gen_candidates,
-                                 prefix_hash)
+from repro.core.itemsets import (Bucket, brute_force_frequent,
+                                 gen_buckets, gen_candidates, prefix_hash)
 from repro.core.tidlist import pack_database
 from repro.data.transactions import load
 
@@ -18,25 +19,108 @@ POLICIES = ["cilk", "fifo", "clustered", "nn"]
 
 
 # ------------------------------------------------------------- planning
-def test_group_by_prefix_partitions_candidates():
-    cands = [(0, 1, 2), (0, 1, 5), (0, 1, 3), (2, 3, 4), (2, 3, 9)]
-    buckets = group_by_prefix(cands)
-    assert len(buckets) == 2
-    regen = [c for b in buckets for c in b.candidates()]
-    assert sorted(regen) == sorted(cands)
-    for b in buckets:
-        assert b.exts == tuple(sorted(b.exts))
-        assert b.key == prefix_hash(b.prefix + (b.exts[0],))
+def _per_pair_candidates(frequent, known_frequent=()):
+    """The per-pair Apriori join + prune that ``gen_buckets`` replaced,
+    kept as the oracle: one tuple per candidate, every (k-1)-subset
+    looked up."""
+    fset = set(frequent) | set(known_frequent)
+    if not frequent:
+        return []
+    k = len(frequent[0]) + 1
+    by_prefix = {}
+    for it in frequent:
+        by_prefix.setdefault(it[:-1], []).append(it[-1])
+    out = []
+    for pref, lasts in by_prefix.items():
+        lasts.sort()
+        for i, a in enumerate(lasts):
+            for b in lasts[i + 1:]:
+                cand = pref + (a, b)
+                if k <= 2 or all(cand[:j] + cand[j + 1:] in fset
+                                 for j in range(k)):
+                    out.append(cand)
+    return out
 
 
-def test_group_by_prefix_on_real_candidates():
+def _group_by_prefix(cands):
+    """The per-candidate regrouping the level driver used to run on the
+    joined candidates: first-seen prefix order, sorted extensions."""
+    groups = {}
+    for c in cands:
+        groups.setdefault((prefix_hash(c), c[:-1]), []).append(c[-1])
+    return [Bucket(h, pref, tuple(sorted(ext)))
+            for (h, pref), ext in groups.items()]
+
+
+def _random_frontier(seed, k, n_items=11):
+    """A seeded random sorted set of (k-1)-itemsets, and a known-frequent
+    set of (k-1)-itemsets the frontier does not hold (itemsets a
+    detached subtree mined)."""
+    rng = np.random.default_rng(seed)
+    every = list(combinations(range(n_items), k - 1))
+    pick = rng.random(len(every))
+    frontier = [c for c, u in zip(every, pick) if u < 0.6]
+    known = [c for c, u in zip(every, pick) if 0.6 <= u < 0.8]
+    return frontier, known
+
+
+def _mushroom_level2():
     db, p = load("mushroom", seed=0)
     bm = pack_database(db[:200], p.n_dense_items)
-    freq = sorted(mine_serial(bm, 60, max_k=2))
-    cands = gen_candidates([f for f in freq if len(f) == 2])
-    buckets = group_by_prefix(cands)
-    assert sum(len(b) for b in buckets) == len(cands)
+    return sorted(f for f in mine_serial(bm, 60, max_k=2) if len(f) == 2)
+
+
+PLAN_CASES = {
+    "empty": lambda: ([], []),
+    "one-item": lambda: ([(4,)], []),
+    **{f"k{k}": (lambda k=k: (_random_frontier(k, k)[0], []))
+       for k in range(2, 6)},
+    **{f"k{k}-known": (lambda k=k: _random_frontier(k, k))
+       for k in range(2, 6)},
+    "hand-k3": lambda: ([(0, 1), (0, 2), (0, 3), (0, 5), (1, 2), (1, 3),
+                         (1, 5), (2, 3), (2, 4), (2, 9), (3, 4), (3, 9)],
+                        []),
+    "mushroom-k3": lambda: (_mushroom_level2(), []),
+}
+
+
+@pytest.mark.parametrize("case", list(PLAN_CASES))
+def test_gen_buckets_equals_grouped_per_pair_candidates(case):
+    """gen_buckets plans a level in bucket form directly: the same
+    buckets, in the same order, with the same key, prefix and
+    extensions as grouping the per-pair join's candidates — so spawn
+    order, flush composition and results are unchanged — and
+    gen_candidates, its flattening, still equals the per-pair join."""
+    frontier, known = PLAN_CASES[case]()
+    oracle = _per_pair_candidates(frontier, known)
+    buckets = gen_buckets(frontier, known)
+    assert ([(b.key, b.prefix, b.exts) for b in buckets]
+            == [(b.key, b.prefix, b.exts)
+                for b in _group_by_prefix(oracle)])
+    assert gen_candidates(frontier, known) == oracle
+    # a partition of the candidates by prefix, extensions sorted, keyed
+    # by the paper's prefix hash
+    assert sum(len(b) for b in buckets) == len(oracle)
     assert len({b.prefix for b in buckets}) == len(buckets)
+    for b in buckets:
+        assert b.exts and b.exts == tuple(sorted(b.exts))
+        assert b.key == prefix_hash(b.prefix + (b.exts[0],))
+    if case == "hand-k3":
+        # (2,5), (3,5) and (4,9) are not in the frontier, so the prune
+        # drops (0,2,5), (0,3,5), (1,2,5), (1,3,5), (2,4,9), (3,4,9)
+        assert [b.prefix for b in buckets] == [(0, 1), (0, 2), (1, 2),
+                                                (2, 3)]
+    if case.startswith("k") and not case.startswith("k2"):
+        # the random frontiers are thin enough that the prune bites
+        grouped = {}
+        for f in frontier:
+            grouped[f[:-1]] = grouped.get(f[:-1], 0) + 1
+        joined = sum(n * (n - 1) // 2 for n in grouped.values())
+        assert len(oracle) < joined
+        if case.endswith("-known"):
+            # known-frequent itemsets keep candidates the frontier
+            # alone would prune
+            assert len(oracle) > len(_per_pair_candidates(frontier))
 
 
 def test_traffic_model_bucket_beats_candidate():

@@ -1,10 +1,12 @@
 """Apriori FPM engine vs brute force, both policies + locality metrics."""
+from collections import Counter
+
 import numpy as np
 import pytest
 from _hyp import given, settings, st
 
 from repro.core.fpm import mine, mine_serial
-from repro.core.itemsets import brute_force_frequent
+from repro.core.itemsets import brute_force_frequent, gen_candidates
 from repro.core.tidlist import pack_database
 from repro.data.transactions import load
 
@@ -15,12 +17,27 @@ def small_db():
     return [t for t in db[:300]], p
 
 
-def test_serial_matches_brute_force(small_db):
+@pytest.mark.parametrize("engine", ["serial", "bucket", "candidate",
+                                    "auto", "hosts=2"])
+def test_serial_matches_brute_force(small_db, engine):
+    """The serial reference and ``mine()`` — at bucket, candidate and
+    auto grain, and split over two hosts — against brute force, at a
+    support where the Apriori prune drops level-3 candidates the
+    prefix join formed."""
     db, p = small_db
     bm = pack_database(db, p.n_dense_items)
-    ms = int(0.3 * len(db))
+    ms = int(0.25 * len(db))
     ref = brute_force_frequent(db, ms, max_k=4)
-    got = mine_serial(bm, ms, max_k=4)
+    level2 = sorted(c for c in ref if len(c) == 2)
+    group_sizes = Counter(c[:-1] for c in level2).values()
+    joined = sum(n * (n - 1) // 2 for n in group_sizes)
+    assert 0 < len(gen_candidates(level2)) < joined
+    if engine == "serial":
+        got = mine_serial(bm, ms, max_k=4)
+    elif engine == "hosts=2":
+        got, _ = mine(bm, ms, n_workers=2, max_k=4, hosts=2)
+    else:
+        got, _ = mine(bm, ms, n_workers=3, max_k=4, granularity=engine)
     assert got == ref
 
 
@@ -82,6 +99,36 @@ def test_property_mine_equals_brute_force_random_db(seed):
     bm = pack_database(db, n_items)
     got, _ = mine(bm, ms, policy="clustered", n_workers=3, max_k=4)
     assert got == ref
+
+
+def test_mine_frees_its_arena_without_the_cyclic_collector(small_db,
+                                                           monkeypatch):
+    """A finished mine drops its arena, and so its device mirror, by
+    reference counting alone: nothing of the run sits in a reference
+    cycle that only the cyclic garbage collector would free."""
+    import gc
+    import weakref
+
+    from repro.core import fpm as fpm_mod
+    db, p = small_db
+    bm = pack_database(db, p.n_dense_items)
+    arenas = []
+    build = fpm_mod.BitmapArena.from_bitmaps
+
+    def tracked(*a, **k):
+        store = build(*a, **k)
+        arenas.append(weakref.ref(store))
+        return store
+
+    monkeypatch.setattr(fpm_mod.BitmapArena, "from_bitmaps", tracked)
+    gc.collect()
+    gc.disable()
+    try:
+        got, _ = mine(bm, int(0.3 * len(db)), n_workers=2, max_k=3)
+        assert got and len(arenas) == 1
+        assert arenas[0]() is None
+    finally:
+        gc.enable()
 
 
 def test_min_support_one_includes_every_item_present():

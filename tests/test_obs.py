@@ -403,6 +403,49 @@ def test_traced_kernel_mine_splits_driver_and_flush(small_db,
     assert sum(e.dur for e in top) >= 0.95 * (last - first)
 
 
+def _level_args(tr):
+    return [e.args for e in tr.events()
+            if e.ph == "X" and e.name.startswith("level-")]
+
+
+def test_level_spans_count_the_pairs_each_level_materialized(small_db):
+    """A plain mine thresholds each bucket's counts before it builds an
+    (itemset, support) pair, so every level-k span reads materialized
+    == frequent, and its buckets are the level's share of
+    ``metrics.buckets``; a streaming refresh (the delta path) keeps
+    every swept support and reads materialized == candidates."""
+    from repro.core.streaming import StreamingMiner
+    db, p = small_db
+    bm = pack_database(db, p.n_dense_items)
+    ms = int(0.25 * len(db))
+    tr = Tracer()
+    res, met = mine(bm, ms, n_workers=2, max_k=4, trace=tr)
+    levels = _level_args(tr)
+    assert len(levels) == met.levels >= 2
+    for a in levels:
+        assert a["materialized"] == a["frequent"]
+        assert 0 < a["buckets"] <= a["candidates"]
+    assert sum(a["buckets"] for a in levels) == met.buckets
+    assert sum(a["candidates"] for a in levels) == met.candidates
+    assert sum(a["frequent"] for a in levels) == \
+        sum(len(c) > 1 for c in res)
+    assert any(a["frequent"] < a["candidates"] for a in levels)
+
+    tr = Tracer()
+    sm = StreamingMiner(p.n_dense_items, ms, initial_db=db[:200],
+                        n_workers=2, max_k=4, tracer=tr)
+    try:
+        sm.refresh()
+        sm.ingest(db[200:300])
+        sm.refresh()
+    finally:
+        sm.close()
+    levels = _level_args(tr)
+    assert levels
+    for a in levels:
+        assert a["materialized"] == a["candidates"] > a["frequent"]
+
+
 def test_traced_streaming_spans_lag_and_latency(small_db):
     from repro.core.streaming import PatternServer, StreamingMiner
     db, p = small_db
