@@ -349,174 +349,155 @@ E_PAD_FLOOR = 128
 
 
 class _PallasBackend(JoinBackend):
-    """Shared plumbing for the kernel modes: pad the ragged batch to
-    [B', E', W], gather rows (on device when the arena has a mirror,
-    host-side otherwise), launch one ``bitmap_join_many`` per
-    transaction segment the batch touches, slice each request's counts
-    back out and sum them across segments. B and E pad to powers of
-    two so the jit cache stays bounded (~log × log shapes per run);
-    single-segment arenas (every non-streaming run) keep the one-launch
-    behaviour.
+    """Shared plumbing for the kernel modes. A flush splits into parts,
+    one per (transaction segment, representation) its batch touches:
+    dense requests go to ``bitmap_join_many``, sparse ones to
+    ``gather_intersect_many``. Full sweeps touch every segment, delta
+    sweeps only the fresh ones; single-segment arenas (every
+    non-streaming run) have one part per representation. Each part is
+    ONE jitted program fed ONE packed int32 descriptor
+    (``_flush_fns``): it gathers its rows from the segment's device
+    mirror, pads W and runs the kernel.
 
-    On a dispatcher thread each launch is three ``flush.*`` spans on
-    its lane — ``flush.prepare`` (index and tid arrays, mirror sync),
-    ``flush.launch`` (the jitted calls, enqueued without waiting) and
-    ``flush.wait`` (the copy of the counts back, which waits for the
-    device) — and adds its logical and padded work to the dispatcher's
-    counters: dense words read ``Σ (L + E) × W`` against
+    A flush launches every part before it reads any counts back: each
+    segment's mirror is synced once, before its first part, and the
+    host builds part B's descriptor while the device runs part A, so
+    the flush waits for the device once, not once per part. Each
+    request's counts are then sliced out and summed across segments.
+    B, L, E, S and W pad to powers of two so the jit cache stays
+    bounded (~log × log shapes per run).
+
+    On a dispatcher thread each part is three ``flush.*`` spans on its
+    lane — ``flush.prepare`` (the descriptor, and the mirror sync
+    before a segment's first part), ``flush.launch`` (the one jitted
+    call, enqueued without waiting) and ``flush.wait`` (the copy of its
+    counts back, which waits for the device) — every launch before the
+    first wait. Each part adds its logical and padded work to the
+    dispatcher's counters: dense words read ``Σ (L + E) × W`` against
     ``B' × (L' + E') × W'``, sparse probes ``Σ S × E`` against
-    ``B' × S' × E'``."""
+    ``B' × S' × E'``; ``overlapped_launches`` counts the launches
+    enqueued while an earlier part of the same flush was unread.
+
+    Without a device mirror (arena backing "numpy") each part's rows
+    are gathered on the host and shipped with its launch, billed to
+    ``h2d_bytes``: the transfer-bound baseline, in the same
+    launch-then-read order."""
 
     mode = "pallas-interpret"
 
     def sweep_many(self, arena, requests):
         work, tracer = _dispatch_thread.work, _dispatch_thread.tracer
-        totals = [np.zeros(len(r.ext_handles), np.int64)
-                  for r in requests]
-        # sub-batch per segment: full sweeps touch every segment, delta
-        # sweeps only the fresh ones — a mixed batch still coalesces
-        # per segment
+        sparse = [r.is_sparse(arena) for r in requests]
         by_seg: Dict[int, List[int]] = {}
         for i, r in enumerate(requests):
             for g in r.segment_ids(arena):
                 if arena.seg_words(g):
                     by_seg.setdefault(g, []).append(i)
+        launched = []          # (request indices, counts on the device)
         for g, idxs in sorted(by_seg.items()):
-            # one flush may mix representations: dense requests go to
-            # bitmap_join_many, sparse ones to gather_intersect_many —
-            # two launches per (segment, mixed batch) at most
-            dense = [i for i in idxs if not requests[i].is_sparse(arena)]
-            sparse = [i for i in idxs if requests[i].is_sparse(arena)]
-            for part, fn in ((dense, self._sweep_segment),
-                             (sparse, self._sweep_segment_sparse)):
+            synced = False
+            for rep, kernel, prepare in (
+                    (False, "bitmap_join", self._prepare_dense),
+                    (True, "gather_intersect", self._prepare_sparse)):
+                part = [i for i in idxs if sparse[i] == rep]
                 if not part:
                     continue
-                counts = fn(arena, g, [requests[i] for i in part],
-                            work, tracer)
-                for j, i in enumerate(part):
-                    totals[i] += counts[j, :len(requests[i].ext_handles)
-                                        ].astype(np.int64)
+                with region(tracer, "flush.prepare", cat="flush"):
+                    if not synced:
+                        dev = arena.device_rows(
+                            requests[idxs[0]].shard,
+                            needed=self._needed(
+                                arena, [requests[i] for i in idxs]),
+                            segment=g)
+                        synced = True
+                    program, args, static = prepare(
+                        arena, g, dev, [requests[i] for i in part], work)
+                with region(tracer, "flush.launch", cat="flush") as a:
+                    if a is not None:
+                        a["kernel"] = kernel
+                    launched.append((part, program(*args, **static)))
+        if work is not None and launched:
+            work["overlapped_launches"] += len(launched) - 1
+        totals = [np.zeros(len(r.ext_handles), np.int64)
+                  for r in requests]
+        for part, out in launched:
+            with region(tracer, "flush.wait", cat="flush"):
+                counts = np.asarray(out)
+            for j, i in enumerate(part):
+                totals[i] += counts[j, :len(requests[i].ext_handles)]
         return totals
 
-    def _sweep_segment(self, arena, seg, requests, work, tracer):
-        gather, dense, _ = _flush_fns(self.mode)
-        with region(tracer, "flush.prepare", cat="flush"):
-            b = len(requests)
-            emax = max(len(r.ext_handles) for r in requests)
-            lmax = max(len(r.prefix_handles) for r in requests)
-            bp = _pow2(b)
-            ep = _pow2(emax, lo=E_PAD_FLOOR)
-            lp = _pow2(lmax)
-            w = arena.seg_words(seg)
-            # pad W to a pow2 too: delta sweeps see one fresh W per
-            # ingest, and without the pad every (segment width, shape)
-            # pair mints a new jit cache entry — recompile stalls that
-            # grow with ingest count. Zero pad words AND to zero and add
-            # no popcount.
-            wp = _pow2(w)
-            pidx = np.zeros((bp, lp), np.int32)
-            eidx = np.zeros((bp, ep), np.int32)
-            mask = np.zeros((bp, ep), bool)
-            for i, r in enumerate(requests):
-                ph = r.prefix_handles
-                # pad the prefix tuple by repeating its first handle —
-                # AND-idempotent, so no mask dimension is needed
-                pidx[i] = (ph + (ph[0],) * (lp - len(ph)))
-                n = len(r.ext_handles)
-                eidx[i, :n] = r.ext_handles
-                mask[i, :n] = True
-            dev = arena.device_rows(requests[0].shard,
-                                    needed=self._needed(arena, requests),
-                                    segment=seg)
-            if dev is None:
-                # host-gather baseline (arena backing "numpy"): the old
-                # transfer-bound behaviour — every batch re-uploads its
-                # bitmap payload, and the gauge records it (pad words
-                # are synthetic zeros, not billed)
-                rows = arena.seg_view(seg)
-                ph = rows[pidx.reshape(-1)].reshape(bp, lp, w)
-                eh = rows[eidx.reshape(-1)].reshape(bp, ep, w)
-                arena.count_h2d(ph[:, 0].nbytes + eh.nbytes)
-                pad = ((0, 0), (0, 0), (0, wp - w))
-                pr, exts = np.pad(ph, pad), np.pad(eh, pad)
-        with region(tracer, "flush.launch", cat="flush") as a:
-            if a is not None:
-                a["kernel"] = "bitmap_join"
-            if dev is not None:
-                # arena-gather path: bitmaps are already device-resident,
-                # only the (tiny) index arrays cross host→device
-                pr, exts = gather(dev, pidx, wp), gather(dev, eidx, wp)
-            out = dense(pr, exts, mask)
-        with region(tracer, "flush.wait", cat="flush"):
-            counts = np.asarray(out)
+    def _prepare_dense(self, arena, seg, dev, requests, work):
+        """Dense part: descriptor ``[B', L' + E']`` — each row the
+        request's prefix tuple, padded by repeating its first handle
+        (AND-idempotent), then its extension handles, padded with -1.
+        Padded rows are all -1."""
+        b = len(requests)
+        bp = _pow2(b)
+        lp = _pow2(max(len(r.prefix_handles) for r in requests))
+        ep = _pow2(max(len(r.ext_handles) for r in requests),
+                   lo=E_PAD_FLOOR)
+        w = arena.seg_words(seg)
+        # pad W to a pow2 too: delta sweeps see one fresh W per ingest,
+        # and without the pad every (segment width, shape) pair mints a
+        # new jit cache entry — recompile stalls that grow with ingest
+        # count. Zero pad words AND to zero and add no popcount.
+        wp = _pow2(w)
+        desc = np.full((bp, lp + ep), -1, np.int32)
+        for i, r in enumerate(requests):
+            ph = r.prefix_handles
+            desc[i, :len(ph)] = ph
+            desc[i, len(ph):lp] = ph[0]
+            desc[i, lp:lp + len(r.ext_handles)] = r.ext_handles
+        if dev is None:
+            dev, desc = _host_rows(arena, seg, desc, 0)
+            arena.count_h2d(dev.nbytes)
         if work is not None:
             work["bitmap_join_launches"] += 1
             work["bitmap_join_words"] += w * sum(
                 len(r.prefix_handles) + len(r.ext_handles)
                 for r in requests)
             work["bitmap_join_padded_words"] += bp * (lp + ep) * wp
-        return counts
+        dense, _ = _flush_fns(self.mode)
+        return dense, (dev, desc), {"lp": lp, "wp": wp}
 
-    def _sweep_segment_sparse(self, arena, seg, requests, work, tracer):
-        """Sparse sub-batch: prefixes are tid/diffset payloads, shipped
-        host→device per launch (billed at actual nbytes — sparse rows
-        have no resident mirror payload); extension word-columns gather
-        from the mirror exactly like the dense path. Tids are
+    def _prepare_sparse(self, arena, seg, dev, requests, work):
+        """Sparse part: descriptor ``[B', S' + E']`` — each row the
+        prefix's tids in this segment, then its extension handles, each
+        padded with -1. Prefixes are tid/diffset payloads, shipped
+        host→device with the descriptor (billed at the tid columns'
+        nbytes — sparse rows have no resident mirror payload); tids are
         searchsorted down to this segment's global tid window and
-        rebased, then padded to a pow2 S with the -1 sentinel so the
-        jit cache stays bounded."""
-        gather, _, sparse = _flush_fns(self.mode)
-        with region(tracer, "flush.prepare", cat="flush"):
-            b = len(requests)
-            emax = max(len(r.ext_handles) for r in requests)
-            bp = _pow2(b)
-            ep = _pow2(emax, lo=E_PAD_FLOOR)
-            w = arena.seg_words(seg)
-            wp = _pow2(w)
-            lo, hi = arena.seg_tid_range(seg)
-            local: List[np.ndarray] = []
-            smax = 1
-            for r in requests:
-                tids = arena.tids_of(r.prefix_handle)
-                i0, i1 = np.searchsorted(tids, [lo, hi])
-                t = (tids[i0:i1].astype(np.int64) - lo).astype(np.int32)
-                local.append(t)
-                smax = max(smax, len(t))
-            sp = _pow2(smax, lo=E_PAD_FLOOR)
-            tmat = np.full((bp, sp), -1, np.int32)
-            for i, t in enumerate(local):
-                tmat[i, :len(t)] = t
-            eidx = np.zeros((bp, ep), np.int32)
-            mask = np.zeros((bp, ep), bool)
-            for i, r in enumerate(requests):
-                n = len(r.ext_handles)
-                eidx[i, :n] = r.ext_handles
-                mask[i, :n] = True
-            dev = arena.device_rows(requests[0].shard,
-                                    needed=self._needed(arena, requests),
-                                    segment=seg)
-            if dev is not None:
-                arena.count_h2d(tmat.nbytes)    # tid payload, per launch
-            else:
-                rows = arena.seg_view(seg)
-                eh = rows[eidx.reshape(-1)].reshape(bp, ep, w)
-                arena.count_h2d(eh.nbytes + tmat.nbytes)
-                exts = np.pad(eh, ((0, 0), (0, 0), (0, wp - w)))
-        with region(tracer, "flush.launch", cat="flush") as a:
-            if a is not None:
-                a["kernel"] = "gather_intersect"
-            if dev is not None:
-                exts = gather(dev, eidx, wp)
-            out = sparse(tmat, exts, mask)
-        with region(tracer, "flush.wait", cat="flush"):
-            counts = np.asarray(out)
+        rebased."""
+        b = len(requests)
+        bp = _pow2(b)
+        ep = _pow2(max(len(r.ext_handles) for r in requests),
+                   lo=E_PAD_FLOOR)
+        w = arena.seg_words(seg)
+        wp = _pow2(w)
+        lo, hi = arena.seg_tid_range(seg)
+        local: List[np.ndarray] = []
+        for r in requests:
+            tids = arena.tids_of(r.prefix_handle)
+            i0, i1 = np.searchsorted(tids, [lo, hi])
+            local.append(tids[i0:i1])
+        sp = _pow2(max(1, max(len(t) for t in local)), lo=E_PAD_FLOOR)
+        desc = np.full((bp, sp + ep), -1, np.int32)
+        for i, (t, r) in enumerate(zip(local, requests)):
+            desc[i, :len(t)] = t - lo
+            desc[i, sp:sp + len(r.ext_handles)] = r.ext_handles
+        arena.count_h2d(bp * sp * 4)              # tid payload, per launch
+        if dev is None:
+            dev, desc = _host_rows(arena, seg, desc, sp)
+            arena.count_h2d(dev.nbytes)
         if work is not None:
             work["gather_intersect_launches"] += 1
             work["gather_intersect_probes"] += sum(
                 len(t) * len(r.ext_handles)
                 for t, r in zip(local, requests))
             work["gather_intersect_padded_probes"] += bp * sp * ep
-        return counts
+        _, sparse = _flush_fns(self.mode)
+        return sparse, (dev, desc), {"sp": sp, "wp": wp}
 
     @staticmethod
     def _needed(arena, requests):
@@ -528,37 +509,61 @@ class _PallasBackend(JoinBackend):
                 for h in (*r.prefix_handles, *r.ext_handles)]
 
 
+def _host_rows(arena, seg, desc, col):
+    """Host-gather baseline: gather the rows named by ``desc``'s columns
+    from ``col`` on from the segment's host store into one ``[n, W]``
+    block, and renumber those columns to index it (-1 sentinels kept),
+    so the part's program reads the block as it would a mirror."""
+    take = desc[:, col:]
+    rows = arena.seg_view(seg)[np.maximum(take, 0).ravel()]
+    desc = desc.copy()
+    desc[:, col:] = np.where(
+        take >= 0, np.arange(take.size, dtype=np.int32).reshape(take.shape),
+        -1)
+    return rows, desc
+
+
 @functools.lru_cache(maxsize=None)
 def _flush_fns(mode: str):
-    """The jitted programs of one kernel mode's flush, built once:
-    ``gather`` reads rows of an arena mirror by handle ([B, K] -> [B, K,
-    Wp], zero pad words), ``dense`` AND-reduces each request's prefix
-    tuple and runs ``bitmap_join_many``, ``sparse`` runs
-    ``gather_intersect_many``. Each compiles once per padded shape
-    (mirror capacity, B, L, E, S, W) — a handful per run — and a flush
-    is a few dispatches rather than a chain of eager ops."""
+    """The two jitted programs of one kernel mode's flush, built once,
+    one per part of a flush. Each takes a segment's mirror ``[cap, W]``
+    and one packed int32 descriptor, gathers the rows it names (zero
+    pad words to ``wp``) and runs the kernel; an extension column of -1
+    is a padded lane, read as row 0 and masked to a count of 0.
+
+    ``dense(mirror, desc [B', L' + E'], lp, wp)`` AND-reduces the first
+    ``lp`` columns' rows into each request's prefix and runs
+    ``bitmap_join_many`` against the rest. ``sparse(mirror, desc
+    [B', S' + E'], sp, wp)`` reads the first ``sp`` columns as tids
+    (-1 = padded lane) and runs ``gather_intersect_many`` against the
+    rest's rows. Each compiles once per padded shape (mirror capacity,
+    B', L' or S', E', W') — a handful per run."""
     import jax
     import jax.numpy as jnp
 
     from repro.kernels.bitmap_join.ops import bitmap_join_many
     from repro.kernels.gather_intersect.ops import gather_intersect_many
 
-    @functools.partial(jax.jit, static_argnames=("wp",))
-    def gather(dev, idx, wp):
-        return jnp.pad(dev[idx], ((0, 0), (0, 0), (0, wp - dev.shape[1])))
+    def rows(mirror, idx, wp):
+        got = mirror[jnp.maximum(idx, 0)]
+        return jnp.pad(got, ((0, 0), (0, 0), (0, wp - mirror.shape[1])))
 
-    @jax.jit
-    def dense(pr, exts, mask):
+    @functools.partial(jax.jit, static_argnames=("lp", "wp"))
+    def dense(mirror, desc, lp, wp):
+        pr, ext = rows(mirror, desc[:, :lp], wp), desc[:, lp:]
         prefixes = pr[:, 0]
-        for j in range(1, pr.shape[1]):   # tuple prefix: AND-reduce
+        for j in range(1, lp):            # tuple prefix: AND-reduce
             prefixes = prefixes & pr[:, j]
-        return bitmap_join_many(prefixes, exts, mask, mode=mode)
+        return bitmap_join_many(prefixes, rows(mirror, ext, wp), ext >= 0,
+                                mode=mode)
 
-    @jax.jit
-    def sparse(tids, exts, mask):
-        return gather_intersect_many(tids, exts, mask, mode=mode)
+    @functools.partial(jax.jit, static_argnames=("sp", "wp"))
+    def sparse(mirror, desc, sp, wp):
+        ext = desc[:, sp:]
+        return gather_intersect_many(desc[:, :sp], rows(mirror, ext, wp),
+                                     ext >= 0, mode=mode)
 
-    return gather, dense, sparse
+    return dense, sparse
 
 
 class PallasInterpretBackend(_PallasBackend):
@@ -880,11 +885,15 @@ class SweepDispatcher:
              **self.work,
              "sweep_s": self.sweep_s})
 
-    def _flush_args(self, batch: Sequence[SweepRequest],
+    def _launches(self) -> int:
+        w = self.work
+        return w["bitmap_join_launches"] + w["gather_intersect_launches"]
+
+    def _flush_args(self, batch: Sequence[SweepRequest], parts: int = 0,
                     inline: bool = False) -> Dict[str, float]:
-        """Span payload for one flush: requests, rows and the
-        dense/sparse representation split. Only runs when a tracer is
-        attached."""
+        """Span payload for one flush: requests, rows, the dense/sparse
+        representation split and ``parts``, the kernel launches it made
+        (0 on a host backend). Only runs when a tracer is attached."""
         arena = self.arena
         rows = sum(len(r.prefix_handles) + len(r.ext_handles)
                    for r in batch)
@@ -892,7 +901,7 @@ class SweepDispatcher:
         return {"requests": len(batch), "rows": rows,
                 "sparse": sparse, "dense": len(batch) - sparse,
                 "queries": sum(1 for r in batch if r.priority),
-                "inline": inline}
+                "parts": parts, "inline": inline}
 
     # -------------------------------------------------------------- loop --
     def _loop(self):
@@ -927,6 +936,7 @@ class SweepDispatcher:
                     t_start - r.t_submit for r in batch))
             try:
                 with region(tr, "flush", cat="flush") as args:
+                    launches = self._launches()
                     t0 = time.perf_counter()
                     results = self.backend.sweep_many(self.arena, batch)
                     with self._cv:
@@ -939,7 +949,8 @@ class SweepDispatcher:
                             results = self.cluster.reduce_flush(
                                 batch, results)
                     if args is not None:
-                        args.update(self._flush_args(batch))
+                        args.update(self._flush_args(
+                            batch, self._launches() - launches))
             except BaseException as e:  # noqa: BLE001 - resolve futures:
                 for r in batch:         # a swallowed error would deadlock
                     r.future.set_exception(e)   # every blocked worker

@@ -43,12 +43,14 @@ SCHEDULER_DERIVED: Tuple[str, ...] = ("tasks_per_steal",)
 DEVICE_ID_KEYS: Tuple[str, ...] = ("device",)      # +"host" in cluster rows
 # per kernel: launches, the logical work of the requests launched
 # (dense: words read, Σ (L + E) × W; sparse: bit probes, Σ S × E), and
-# the padded work actually launched (B' × (L' + E') × W', B' × S' × E')
+# the padded work actually launched (B' × (L' + E') × W', B' × S' × E');
+# overlapped_launches: launches a flush enqueued while an earlier part
+# of the same flush was still unread (its launches − 1, per flush)
 KERNEL_COUNTERS: Tuple[str, ...] = (
     "bitmap_join_launches", "bitmap_join_words",
     "bitmap_join_padded_words",
     "gather_intersect_launches", "gather_intersect_probes",
-    "gather_intersect_padded_probes",
+    "gather_intersect_padded_probes", "overlapped_launches",
 )
 # queue_wait_us: µs from submit to the start of the carrying flush,
 # summed over the dispatcher queue's requests

@@ -60,6 +60,75 @@ def test_numpy_vs_pallas_interpret_parity_ragged(backing):
         assert y.dtype == np.int64
 
 
+def flush_arena(backing, widths):
+    """Twelve base item rows over segments of the given word widths, a
+    materialized dense row, two tid-lists and a diffset (over the whole
+    tid range, so each segment holds some of their tids)."""
+    n = 12
+    arena = BitmapArena.from_bitmaps(
+        RNG.integers(0, 2 ** 32, size=(n, widths[0]), dtype=np.uint32),
+        backing=backing)
+    for w in widths[1:]:
+        arena.add_segment(RNG.integers(0, 2 ** 32, size=(n, w),
+                                       dtype=np.uint32))
+    bits = 32 * arena.n_words
+
+    def tids(k):
+        return np.sort(RNG.choice(bits, size=min(k, bits), replace=False))
+
+    handles = {"and": arena.materialize(0, 1),
+               "tids": arena.push_tids(tids(40)),
+               "tids_long": arena.push_tids(tids(300))}
+    handles["diff"] = arena.push_diffset(tids(9), anchor=0, support=1)
+    return arena, handles
+
+
+# (arena backing, segment widths, [(prefix, exts, segments)]): prefix is
+# an item handle, a tuple of them, or the name of a pushed row
+FLUSHES = {
+    "mixed": ("auto", (40,), [
+        (0, range(1, 12), None), ("tids", (2, 5, 7), None),
+        ("and", (3, 4), None), ("diff", range(12), None),
+        ("tids_long", (), None), (5, (), None)]),
+    "tuple-prefixes": ("auto", (40,), [
+        ((0, 1), range(2, 12), None), ((2, 3, 4), (5, 6), None),
+        (7, (8,), None), ((9, 10, 11, 0, 1), (2, 3, 4), None),
+        ("tids", (1, 2), None)]),
+    "delta-segments": ("auto", (40, 3, 70), [
+        ((0, 1), range(2, 12), (1, 2)), (3, (4, 5, 6), (2,)),
+        ("tids_long", range(12), (1, 2)), ("tids", (0, 11), None),
+        ((6, 7), (), (1,)), (8, (9, 10), None)]),
+    "host-gather": ("numpy", (40, 5), [
+        ((0, 1), range(2, 12), None), ("tids_long", range(12), None),
+        ("and", (2, 3), (1,)), ("diff", (4, 5, 6), None),
+        (9, (), None)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLUSHES))
+def test_packed_flush_matches_numpy_backend(case):
+    """Each flush part's packed descriptor (prefix or tid columns, then
+    extension handles, -1 sentinels) gives the numpy backend's counts:
+    dense and sparse parts launched together, tuple prefixes of several
+    lengths, ragged and empty extension lists, delta sweeps over
+    segments of different widths, and the host-gather baseline."""
+    backing, widths, specs = FLUSHES[case]
+    arena, handles = flush_arena(backing, widths)
+
+    def requests():
+        return [jb.SweepRequest(handles.get(p, p) if isinstance(p, str)
+                                else p, tuple(e), segments=seg)
+                for p, e, seg in specs]
+
+    want = jb.get_backend("numpy").sweep_many(arena, requests())
+    got = jb.get_backend("pallas-interpret").sweep_many(arena, requests())
+    assert len(got) == len(want) == len(specs)
+    for (p, e, _), x, y in zip(specs, want, got):
+        assert y.dtype == np.int64 and len(y) == len(tuple(e))
+        np.testing.assert_array_equal(y, x, err_msg=str(p))
+    assert any(x.any() for x in want)
+
+
 def test_bitmap_join_many_mask_zeroes_padding():
     import jax.numpy as jnp
 

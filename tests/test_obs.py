@@ -403,6 +403,55 @@ def test_traced_kernel_mine_splits_driver_and_flush(small_db,
     assert sum(e.dur for e in top) >= 0.95 * (last - first)
 
 
+def test_mixed_flush_launches_every_part_before_its_first_wait():
+    """A flush over two segments with dense and sparse requests has four
+    parts (segment × representation), and enqueues all four launches
+    before it reads any counts back; a delta flush of one dense part
+    after it has nothing to overlap. ``overlapped_launches`` is the
+    launches less the flushes that launched anything, and each launch
+    keeps its own prepare/launch/wait triple."""
+    import numpy as np
+
+    from repro.core.join_backend import SweepDispatcher, get_backend
+    from repro.core.tidlist import BitmapArena
+    rng = np.random.default_rng(17)
+    arena = BitmapArena.from_bitmaps(
+        rng.integers(0, 2 ** 32, size=(12, 40), dtype=np.uint32))
+    arena.add_segment(rng.integers(0, 2 ** 32, size=(12, 3),
+                                   dtype=np.uint32))
+    t = arena.push_tids(np.sort(rng.choice(32 * 43, 200, replace=False)))
+    tr = Tracer()
+    disp = SweepDispatcher(arena, get_backend("pallas-interpret"),
+                           n_clients=3, flush_us=5e6, tracer=tr)
+    try:
+        for f in disp.submit_many([((0, 1), tuple(range(2, 12))),
+                                   (t, (1, 2, 3)), (4, (5, 6))]):
+            f.result(timeout=60)
+        disp.submit_many([(5, (6, 7))], segments=(1,))[0].result(
+            timeout=60)
+    finally:
+        disp.stop()
+    spans = [e for e in tr.events()
+             if e.ph == "X" and e.lane == "dispatcher-0"]
+    flushes = [e for e in spans if e.name == "flush"]
+    assert [e.args["parts"] for e in flushes] == [4, 1]
+    for fl in flushes:
+        inner = [e for e in spans if e.name.startswith("flush.")
+                 and fl.ts <= e.ts <= fl.ts + fl.dur]
+        launches = [e.ts for e in inner if e.name == "flush.launch"]
+        waits = [e.ts for e in inner if e.name == "flush.wait"]
+        assert len(launches) == len(waits) == fl.args["parts"]
+        assert max(launches) < min(waits)
+    row = disp.stats()
+    n_launch = row["bitmap_join_launches"] + row["gather_intersect_launches"]
+    assert n_launch == 5 == sum(e.name == "flush.launch" for e in spans)
+    for name in ("flush.prepare", "flush.wait"):
+        assert sum(e.name == name for e in spans) == n_launch
+    assert row["overlapped_launches"] == n_launch - sum(
+        e.args["parts"] > 0 for e in flushes) == 3
+    assert check_nesting(tr.events()) == []
+
+
 def _level_args(tr):
     return [e.args for e in tr.events()
             if e.ph == "X" and e.name.startswith("level-")]

@@ -63,3 +63,24 @@ def test_gather_intersect_many_compiles_for_v5e(one_chip, b, e, w, s):
     text = _compiled_text(gather_intersect_many_kernel, one_chip,
                           ((b, s), jnp.int32), ((b, e, w), jnp.uint32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kind,b,lead,e,w,cap", [
+    ("dense", 8, 1, 1024, 3125, 4096),      # a level-2 bucket batch
+    ("dense", 4, 2, 128, 70, 512),          # tuple prefixes, delta width
+    ("sparse", 8, 1024, 128, 3125, 4096)])  # tid columns lead
+def test_packed_flush_programs_compile_for_v5e(one_chip, kind, b, lead, e,
+                                               w, cap):
+    """One flush part is one program: the mirror and one packed int32
+    descriptor in, the row gather, W pad and kernel fused behind it."""
+    from repro.core.join_backend import _flush_fns
+    from repro.core.tidlist import pow2
+
+    dense, sparse = _flush_fns("pallas-jit")
+    mirror = jax.ShapeDtypeStruct((cap, w), jnp.uint32, sharding=one_chip)
+    desc = jax.ShapeDtypeStruct((b, lead + e), jnp.int32, sharding=one_chip)
+    if kind == "dense":
+        lowered = dense.lower(mirror, desc, lp=lead, wp=pow2(w))
+    else:
+        lowered = sparse.lower(mirror, desc, sp=lead, wp=pow2(w))
+    assert "tpu_custom_call" in lowered.compile().as_text()
