@@ -42,8 +42,7 @@ def run(datasets: List[str] = DATASETS, n_workers: int = 4,
     """``granularity="candidate"`` reproduces the paper's per-itemset
     tasks (Fig. 1's setting — the cache hit-rate gap is the story);
     ``"bucket"`` runs the same policy contrast on the vectorized
-    bucket-sweep engine (see benchmarks/fpm_granularity.py for the
-    granularity A/B itself)."""
+    bucket-sweep engine."""
     rows = []
     for name in datasets:
         scale, frac = BENCH_SETUP[name]

@@ -25,8 +25,7 @@ def run(dataset: str = "mushroom", workers=(1, 2, 4, 8),
     for n in workers:
         # candidate granularity: efficiency is measured against the
         # per-candidate serial join, so the engine must do the same
-        # per-task work (the bucket engine's A/B lives in
-        # fpm_granularity.py)
+        # per-task work
         _, met = mine(bm, ms, policy="clustered", n_workers=n,
                       max_k=max_k, granularity="candidate")
         rows.append({"workers": n, "wall_s": met.wall_s,

@@ -2,8 +2,8 @@
 
 1. Generate a transaction database (FIMI-profile synthetic).
 2. Mine it with the Cilk-style policy, then the clustered policy, at
-   candidate granularity (one scalar join per task — the paper's §2
-   setting) and show the locality metrics that explain the difference
+   candidate granularity (one single-extension sweep per task — the
+   paper's §2 setting) and show the locality metrics that explain the difference
    (the Fig. 1 + Table 1 story).
 3. Re-mine at bucket granularity: one task per (k-1)-prefix, the prefix
    intersection computed once, all extensions swept in one vectorized

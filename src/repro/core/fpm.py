@@ -4,10 +4,11 @@ Three task granularities (the paper's key knob, cf. "Redesigning pattern
 mining algorithms for supercomputers"):
 
   granularity="candidate"    one task per candidate k-itemset (paper §2).
-      The per-task join reuses a per-worker-thread LRU cache of *prefix
-      intersections*: tasks that share a (k-1)-prefix hit the cache iff
-      they run back-to-back on the same worker — exactly the locality
-      the clustered policy creates and the Cilk-style policy destroys.
+      The task's prefix comes from a per-worker-thread LRU cache of
+      *prefix intersections*: tasks that share a (k-1)-prefix hit the
+      cache iff they run back-to-back on the same worker — exactly the
+      locality the clustered policy creates and the Cilk-style policy
+      destroys. Its one-extension join is a dispatcher request.
   granularity="bucket"       one task per (k-1)-prefix bucket (default).
       The task resolves its prefix intersection ONCE (to an arena
       handle) and enqueues one handle-based SweepRequest on the sweep
@@ -31,7 +32,8 @@ bitmaps are loaded once (handle == item id), prefix intersections and
 child handoffs are refcounted arena rows, and on the Pallas path the
 arena's device mirror is synced incrementally — repeated sweeps cost
 ~one initial upload (``MiningMetrics.h2d_bytes``) instead of one
-upload per sweep.
+upload per sweep. Every join of every granularity, on every backend,
+is a ``SweepRequest`` flushed by a dispatcher thread.
 
 ``mine(mesh=...)`` runs the SAME engine — every granularity, every
 policy — across a device mesh: the arena shards one mirror per device
@@ -521,10 +523,6 @@ class MiningRun:
         self.sched = runtime.sched
         self.metrics = MiningMetrics(n_devices=store.n_shards)
         self.caches: Dict[int, _PrefixCache] = {}   # thread ident -> cache
-        # cluster mode also forces dispatcher-routed joins: a direct
-        # host join would skip the cross-host reduction
-        self.sweep_joins = (store.n_shards > 1
-                            or runtime.cluster is not None)
         # gauge baselines: zero for an owned runtime, the accumulated
         # counters for a borrowed one — finalize() reports deltas
         self._disp0 = [d.stats() for d in self.dispatchers]
@@ -612,7 +610,7 @@ def mine(bitmaps: np.ndarray, min_support: int, *,
 
     ``granularity`` selects the unit of scheduler task: "bucket" (one
     task per (k-1)-prefix, batched extension sweep), "candidate"
-    (one scalar join per candidate — kept for A/B benchmarking),
+    (one single-extension sweep per candidate, the paper's task),
     "depth-first" (barrier-free equivalence-class recursion with
     parent→child handle handoff), or "auto" (levelwise driver that
     detaches subtrees to depth-first class tasks when the density
@@ -720,26 +718,21 @@ def mine_more(run: MiningRun, min_support: int, max_k: int,
         _mine_levelwise(run.store, run.dispatchers, min_support, max_k,
                         run.sched, run.metrics, result, frequent,
                         run.granularity, run.cache_size, run.caches,
-                        sweep_joins=run.sweep_joins, delta=delta,
-                        model=run.model, cluster=cluster)
+                        delta=delta, model=run.model, cluster=cluster)
 
 
 def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
                     metrics, result, frequent, granularity, cache_size,
-                    caches, sweep_joins=False, delta=None, model=None,
-                    cluster=None):
+                    caches, delta=None, model=None, cluster=None):
     """Level-synchronous engines: plan level k, spawn, barrier, plan
     level k+1 (the paper's §2 shape, at candidate or bucket grain).
     The planner is ``gen_buckets``: a level stays in prefix-bucket form
     from candidate generation to thresholding (candidate grain
     flattens it only to spawn), and a plain mine's collect pairs up
     only the frequent extensions, so no per-candidate tuple is built
-    on the driver's serial path.
-    ``sweep_joins`` routes even candidate-granularity scalar joins
-    through the (per-device) dispatchers — multi-shard runs need every
-    row access on the owning shard's path for d2d accounting;
-    single-shard runs (shared-memory or a 1-device mesh) keep the
-    direct host join as the scalar baseline.
+    on the driver's serial path. A candidate task is a bucket task
+    whose bucket holds one extension, so both grains sweep through the
+    worker's device-affine dispatcher.
 
     With a ``delta`` plan the level's candidates split three ways:
     *clean known* (support unchanged — zero rows touched), *dirty
@@ -812,34 +805,6 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
         st.rows_touched += prows + erows
         st.bytes_swept += (rows_to_bytes(prows, n_w)
                            + rows_to_bytes(erows, _seg_w(segments)))
-
-    def count_task(cand: Itemset, segments=None) -> int:
-        cache = _thread_cache()
-        ph, prows = _prefix_handle(cache, cand[:-1])
-        try:
-            _account(prows, 1, segments)
-            st = sched.worker_stats()
-            sparse = store.rep_of(ph) != tidlist.REP_BITMAP
-            if sparse:
-                st.sparse_sweeps += 1
-                st.sparse_bytes_swept += len(store.tids_of(ph)) * 4
-            else:
-                st.dense_sweeps += 1
-            if sweep_joins or segments is not None:
-                st.sweeps_submitted += 1
-                disp = dispatchers[sched.worker_device()]
-                return int(disp.sweep(ph, (cand[-1],),
-                                      segments=segments,
-                                      desc=cand[:-1])[0])
-            if sparse:
-                # cached sparse prefixes are tid-lists (never
-                # diffsets), so the gather count IS the support
-                return int(tidlist.gather_count(store.tids_of(ph),
-                                                store.row(cand[-1])))
-            return int(tidlist.popcount32(store.row(ph)
-                                          & store.row(cand[-1])).sum())
-        finally:
-            store.release(ph)
 
     def sweep_task(bucket: Bucket, segments=None) -> np.ndarray:
         """Bucket-granularity body: resolve the prefix handle once,
@@ -914,15 +879,19 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
         return plan, tasks
 
     def _spawn_candidates(plan: List[Bucket], segments):
+        """One task per candidate: a sweep of its prefix's bucket cut
+        down to its one extension."""
         prio = delta.priority_of if delta is not None else None
         tenant = delta.tenant if delta is not None else None
         cands, tasks = [], []
         for b in plan:
             priority = prio(b.prefix) if prio else 0.0
-            for c in b.candidates():
+            for e in b.exts:
+                c = b.prefix + (e,)
                 cands.append(c)
-                tasks.append(sched.spawn(count_task, c, segments,
-                                         attr=(b.key, c),
+                tasks.append(sched.spawn(sweep_task,
+                                         Bucket(b.key, b.prefix, (e,)),
+                                         segments, attr=(b.key, c),
                                          priority=priority,
                                          tenant=tenant))
         return cands, tasks
@@ -931,15 +900,15 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
                          ) -> List[Tuple[Itemset, int]]:
         """Coalesced dirty-candidate burst: each bucket in the chunk
         becomes ONE tuple-prefix sweep over the pending segments, and
-        the whole chunk executes as a single burst — on this worker
-        thread for host backends, or as one dispatcher flush for
-        kernel backends. No prefix bitmap is ever built host-side."""
+        the whole chunk is enqueued at once, so it coalesces into wide
+        dispatcher flushes. No prefix bitmap is ever built host-side."""
         st = sched.worker_stats()
         disp = dispatchers[sched.worker_device()]
-        counts_per_bucket = disp.sweep_local(
+        futures = disp.submit_many(
             [((b.prefix if len(b.prefix) > 1 else b.prefix[0]),
               b.exts) for b in chunk],
             segments=delta.segments)
+        counts_per_bucket = [f.result() for f in futures]
         st.sweeps_submitted += len(chunk)
         out: List[Tuple[Itemset, int]] = []
         rows = 0
@@ -1013,7 +982,7 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
 
             def collect():
                 _raise_task_errors(tasks)
-                return [(c, int(t.result))
+                return [(c, int(t.result[0]))
                         for c, t in zip(cands, tasks)]
         return collect
 
@@ -1159,15 +1128,6 @@ class _ClassMiner:
     parent are carved out of P's explicit tid set (``resolve_tids``,
     reconstructed once per class), so no dense intermediate is built.
 
-    On host_parallel backends sparse subtrees run PROJECTED instead:
-    the class sweep's [E, S] bit matrix (``sweep_bits``) is the dEclat
-    recursion state — a child class receives its sibling rows
-    column-masked to its own tid positions, its supports are row sums,
-    and no arena row, dispatcher hop, or gather exists anywhere in the
-    subtree's interior. Kernel backends keep the arena handoff path
-    (device-resident rows, diffset chains, per-class gather-intersect
-    sweeps).
-
     Memory bound: a handed row is live from materialize until the
     child task's ``finally`` releases it (including on task error — an
     error may NOT leak the refcount, or the arena slot never recycles).
@@ -1225,8 +1185,7 @@ class _ClassMiner:
         """One child handoff row in the model-picked representation.
         Returns (handle, handoff-bytes-read, is-sparse). ``ptids`` is
         P's explicit tid set and ``bits`` its membership row in ext e —
-        both resolved/gathered ONCE per class by the caller (from the
-        sweep's own bit matrix when the backend surfaced it); only the
+        both resolved/gathered ONCE per class by the caller; only the
         dense-parent materialize path runs without them."""
         store = self.store
         if crep == "bitmap" and store.rep_of(ph) == tidlist.REP_BITMAP:
@@ -1254,60 +1213,33 @@ class _ClassMiner:
     def class_task(self, prefix: Itemset, ph: int,
                    exts: Tuple[int, ...], psup: Tuple[int, ...],
                    own_support: int, owned: bool,
-                   ptids_hint=None, sub=None) -> None:
+                   ptids_hint=None) -> None:
         store, sched, delta = self.store, self.sched, self.delta
         min_support, model = self.min_support, self.model
         children: List[Tuple[Itemset, int, Tuple[int, ...],
-                             Tuple[int, ...], int, object,
-                             object]] = []
+                             Tuple[int, ...], int, object]] = []
         try:
             k = len(prefix) + 1                 # size of swept itemsets
             shard = sched.worker_device()
             st = sched.worker_stats()
             disp = self.dispatchers[shard]
-            # host backends mine sparse subtrees projected (see the
-            # children block); a projected child is a positional tid
-            # mask whose sweep reads child_support bools no matter how
-            # it was notionally encoded — so diffsets' smaller size
-            # buys nothing there and the model must not price them
-            host = delta is None and disp.backend.host_parallel
-            if sub is not None:
-                # projected class: ``sub`` is the subtree root's
-                # gather-intersect bit matrix, row-selected to this
-                # class's extensions and column-sliced to its tid
-                # positions — no arena row exists for P at all
-                rep = None
-                sparse = True
-                is_diff = False
-                payload = sub.shape[1]
-            else:
-                rep = store.rep_of(ph)
-                sparse = rep != tidlist.REP_BITMAP
-                payload = len(store.tids_of(ph)) if sparse else 0
-                is_diff = rep == tidlist.REP_DIFFSET
-            pbits = None      # sweep's own [E, S] payload∩ext matrix
+            rep = store.rep_of(ph)
+            sparse = rep != tidlist.REP_BITMAP
+            payload = len(store.tids_of(ph)) if sparse else 0
+            is_diff = rep == tidlist.REP_DIFFSET
             supports: List[Tuple[int, int]] = []     # (ext, support)
             if delta is None:
-                if sub is not None:
-                    # support of P+e is a masked row sum — the dEclat
-                    # intersection collapsed to boolean algebra
-                    counts = sub.sum(axis=1, dtype=np.int64)
-                    pbits = sub
+                st.sweeps_submitted += 1
+                counts = disp.sweep(ph, exts, desc=prefix)
+                if is_diff:
+                    # dEclat arithmetic: the backend counted
+                    # |diff ∩ e|; the parent's sibling supports
+                    # handed down at spawn turn it into support
+                    supports = [(e, psup[j] - int(s)) for j, (e, s)
+                                in enumerate(zip(exts, counts))]
+                else:
                     supports = [(e, int(s))
                                 for e, s in zip(exts, counts)]
-                else:
-                    st.sweeps_submitted += 1
-                    counts, pbits = disp.sweep_bits(ph, exts,
-                                                    desc=prefix)
-                    if is_diff:
-                        # dEclat arithmetic: the backend counted
-                        # |diff ∩ e|; the parent's sibling supports
-                        # handed down at spawn turn it into support
-                        supports = [(e, psup[j] - int(s)) for j, (e, s)
-                                    in enumerate(zip(exts, counts))]
-                    else:
-                        supports = [(e, int(s))
-                                    for e, s in zip(exts, counts)]
                 swept = len(exts)
                 fresh_e: List[int] = []
                 dirty_e: List[int] = []
@@ -1379,83 +1311,30 @@ class _ClassMiner:
                                  "bitmap" if model is None
                                  else model.pick_child_rep(
                                      own_support, csup,
-                                     allow_diffset=delta is None
-                                     and not host)))
-                # host backends mine sparse subtrees PROJECTED: the
-                # sweep's gather-intersect bit matrix, row-selected to
-                # the frequent siblings, IS the dEclat recursion state.
-                # A child class's supports are column-masked row sums
-                # of its parent's matrix, so the whole subtree below
-                # this class runs on boolean index algebra — no arena
-                # rows, no dispatcher hops, no gathers. Kernel backends
-                # keep arena handoffs (the device owns the rows;
-                # projection would drag every class to the host).
-                proj = host and (sparse
-                                 or any(p[3] != "bitmap" for p in plan))
-                fmat = None   # frequent-sibling bits over P's tid set
+                                     allow_diffset=delta is None)))
                 ptids = None  # P's tid set, resolved at most once
-                bcol: Dict[int, int] = {}   # ext -> row in bit matrix
-                bmat = None
-                if proj and plan:
-                    if pbits is not None and not is_diff:
-                        eidx = {e: j for j, e in enumerate(exts)}
-                        fmat = pbits[[eidx[f] for f in sibs]]
+                bits: Dict[int, np.ndarray] = {}  # ext -> P's bits in it
+                carve = [e for _, e, _, crep in plan
+                         if crep != "bitmap" or sparse]
+                if carve:
+                    if is_diff:
+                        # dEclat chain: the spawner handed P's parent
+                        # tid set down, so resolution is ONE sorted
+                        # difference, not a chain walk
+                        diff = store.tids_of(ph)
+                        ptids = (tidlist.sorted_difference(
+                                     ptids_hint, diff)
+                                 if ptids_hint is not None
+                                 else store.resolve_tids(ph))
+                    elif sparse:
+                        ptids = store.tids_of(ph)
                     else:
-                        if is_diff:
-                            # dEclat chain: the spawner handed P's
-                            # parent tid set down, so resolution is ONE
-                            # sorted difference, not a chain walk
-                            diff = store.tids_of(ph)
-                            ptids = (tidlist.sorted_difference(
-                                         ptids_hint, diff)
-                                     if ptids_hint is not None
-                                     else store.resolve_tids(ph))
-                        elif sparse:
-                            ptids = store.tids_of(ph)
-                        else:
-                            ptids = store.resolve_tids(ph)  # billed
-                        fmat = store.gather_bits_rows(ptids, sibs)
-                        child_bytes += len(ptids) * 4
-                elif plan and not host:
-                    carve = [p for p in plan
-                             if p[3] != "bitmap"
-                             or rep != tidlist.REP_BITMAP]
-                    if carve:
-                        if is_diff:
-                            diff = store.tids_of(ph)
-                            ptids = (tidlist.sorted_difference(
-                                         ptids_hint, diff)
-                                     if ptids_hint is not None
-                                     else store.resolve_tids(ph))
-                            pbits = None  # sweep bits were over diff
-                        elif sparse:
-                            ptids = store.tids_of(ph)
-                        else:
-                            ptids = store.resolve_tids(ph)  # billed
-                        if pbits is not None:
-                            eidx = {e: j for j, e in enumerate(exts)}
-                            bcol = {e: eidx[e] for _, e, _, _ in carve}
-                            bmat = pbits
-                        else:
-                            ce = [e for _, e, _, _ in carve]
-                            bmat = store.gather_bits_rows(ptids, ce)
-                            bcol = {e: j for j, e in enumerate(ce)}
+                        ptids = store.resolve_tids(ph)  # billed
+                    bits = dict(zip(carve,
+                                    store.gather_bits_rows(ptids, carve)))
                 for i, e, csup, crep in plan:
-                    if proj and (crep != "bitmap" or sparse):
-                        m = fmat[i]
-                        csub = fmat[i + 1:len(freq)][:, m]
-                        read = csub.nbytes + m.nbytes
-                        child_bytes += read
-                        child_sparse_bytes += read
-                        children.append((prefix + (e,), -1,
-                                         tuple(sibs[i + 1:]),
-                                         tuple(s for _, s
-                                               in freq[i + 1:]),
-                                         csup, None, csub))
-                        continue
                     ch, read, ch_sparse = self._make_child(
-                        ph, e, csup, crep, shard, ptids,
-                        bmat[bcol[e]] if e in bcol else None)
+                        ph, e, csup, crep, shard, ptids, bits.get(e))
                     child_bytes += read
                     if ch_sparse:
                         child_sparse_bytes += read
@@ -1464,17 +1343,15 @@ class _ClassMiner:
                                      tuple(s for _, s in freq[i + 1:]),
                                      csup,
                                      ptids if crep == "diffset"
-                                     else None, None))
+                                     else None))
             if delta is None:
                 rows = class_rows_touched(len(exts), len(children))
                 st.rows_touched += rows
                 if sparse:
                     # gather-intersect passes: the payload once per
                     # extension (plus once for itself), never W words —
-                    # plus the measured child-handoff reads. Projected
-                    # classes read exactly their bit matrix.
-                    sb = (sub.nbytes if sub is not None
-                          else payload * 4 * (1 + len(exts)))
+                    # plus the measured child-handoff reads
+                    sb = payload * 4 * (1 + len(exts))
                     st.bytes_swept += sb + child_bytes
                     st.sparse_bytes_swept += sb + child_sparse_bytes
                 else:
@@ -1515,11 +1392,9 @@ class _ClassMiner:
                     self.result[prefix + (e,)] = s
             spawned = []
             while children:
-                (cprefix, ch, csibs, cpsup, csup, chint,
-                 csub) = children[0]
+                cprefix, ch, csibs, cpsup, csup, chint = children[0]
                 spawned.append(self.spawn(cprefix, ch, csibs, cpsup,
-                                          csup, csub is None, chint,
-                                          csub))
+                                          csup, True, chint))
                 children.pop(0)       # ownership moved to the child task
             if spawned:
                 with self.lock:
@@ -1527,23 +1402,20 @@ class _ClassMiner:
         except BaseException:
             # refcount hygiene on error: materialized handles whose
             # child tasks never spawned must release here or the rows
-            # leak for the rest of the run (projected children own
-            # nothing — their state is the sliced bit matrix)
-            for _, ch, _, _, _, _, csub in children:
-                if csub is None:
-                    store.release(ch)
+            # leak for the rest of the run
+            for _, ch, _, _, _, _ in children:
+                store.release(ch)
             raise
         finally:
             if owned:
                 store.release(ph)
 
     def spawn(self, prefix: Itemset, ph: int, exts, psup,
-              own_support: int, owned: bool, ptids_hint=None,
-              sub=None):
+              own_support: int, owned: bool, ptids_hint=None):
         delta = self.delta
         return self.sched.spawn(
             self.class_task, prefix, ph, exts, psup, own_support, owned,
-            ptids_hint, sub,
+            ptids_hint,
             attr=(itemset_hash(prefix), prefix), depth=len(prefix),
             priority=(delta.priority_of(prefix)
                       if delta is not None and delta.priority_of

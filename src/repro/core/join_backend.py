@@ -19,13 +19,15 @@ This layer inverts that around two pieces:
       pending requests into a padded batch and launches ONE
       multi-prefix ``bitmap_join_many`` kernel for all of them. Only
       the dispatcher thread ever touches JAX — no lock exists at all.
+      Every sweep of every granularity, on every backend, is such a
+      request: no engine path calls a backend itself.
 
 Backends implement the same batched API:
 
   numpy             per-request ``tidlist.support_counts`` over
-                    zero-copy arena row views — GIL-released ufunc
-                    passes, the CPU tier-1 path. It runs through the
-                    identical dispatcher/batching code as the kernels.
+                    zero-copy arena row views — the CPU tier-1 path,
+                    called from the same dispatcher flushes as the
+                    kernels, so tier-1 runs the chip's control flow.
   pallas-interpret  ``bitmap_join_many`` under the Pallas interpreter —
                     bit-exact with the TPU kernel, runnable anywhere.
   pallas-jit        the compiled kernel — TPU only; each request's
@@ -132,12 +134,11 @@ class SweepRequest:
 class _DispatchThread(threading.local):
     """What a backend reads on the calling dispatcher thread: that
     dispatcher's per-kernel counters (``SweepDispatcher.work``) and its
-    tracer, for the ``flush.*`` spans on its lane. Elsewhere
-    (an inline host burst, or a backend called directly) both stay
-    None, and nothing is counted or traced. Per thread, so one shared
-    backend serves every shard's dispatcher and ``sweep_many(arena,
-    requests)`` keeps the two-argument form that backend subclasses
-    and wrappers override."""
+    tracer, for the ``flush.*`` spans on its lane. Elsewhere (a
+    backend called directly) both stay None, and nothing is counted or
+    traced. Per thread, so one shared backend serves every shard's
+    dispatcher and ``sweep_many(arena, requests)`` keeps the
+    two-argument form that backend subclasses and wrappers override."""
 
     work: Optional[Dict[str, int]] = None
     tracer = None
@@ -152,10 +153,6 @@ class JoinBackend:
     request's own extension count)."""
 
     name: str = "base"
-    # True when ``sweep_many`` is safe to call from ANY thread (pure
-    # host compute against the arena's locked bookkeeping). Kernel
-    # backends stay False: only the dispatcher thread may touch JAX.
-    host_parallel: bool = False
 
     def sweep_many(self, arena: BitmapArena,
                    requests: Sequence[SweepRequest]) -> List[np.ndarray]:
@@ -173,7 +170,7 @@ class NumpyBackend(JoinBackend):
     instead of ~10 tiny numpy calls per request. On the streaming
     delta path the per-request work is a 2-word AND — Python call
     overhead dwarfed the arithmetic until the batch was vectorized.
-    Runs through the same dispatcher path as the kernels so CPU
+    Called only from dispatcher flushes, like the kernels, so CPU
     tier-1 tests exercise the identical request/batch/flush
     machinery. In sharded mode the batch's row accesses are booked
     against the requests' shard first (cross-shard reads land in the
@@ -182,7 +179,6 @@ class NumpyBackend(JoinBackend):
     the flush has no ``flush.wait``."""
 
     name = "numpy"
-    host_parallel = True
     # bound on a bin pass's [B, E, W] AND temporary (slices B)
     PASS_BYTES = 4 << 20
 
@@ -242,36 +238,6 @@ class NumpyBackend(JoinBackend):
         return [t if t is not None
                 else np.zeros(len(r.ext_handles), np.int64)
                 for t, r in zip(totals, requests)]
-
-    @staticmethod
-    def sweep_sparse_bits(arena, r):
-        """Sparse sweep that also returns the gathered bit matrix.
-
-        A depth-first class task needs |payload ∩ e| to COUNT and
-        payload ∩ e to CARVE child rows — both fall out of one [E, S]
-        gather. The host-parallel path returns ``(counts, bits)`` so
-        the engine never re-gathers what the count pass already read
-        (the device kernel returns counts only; the engine falls back
-        to a batched carve gather there). ``bits`` columns align with
-        the request's sorted payload; full sweeps only."""
-        tids = arena.tids_of(r.prefix_handle)
-        n_ext, n_tid = len(r.ext_handles), len(tids)
-        bits = np.zeros((n_ext, n_tid), bool)
-        if not n_ext or not n_tid:
-            return np.zeros(n_ext, np.int64), bits
-        eh = list(r.ext_handles)
-        for g in r.segment_ids(arena):
-            if not arena.seg_words(g):
-                continue
-            lo, hi = arena.seg_tid_range(g)
-            i0, i1 = np.searchsorted(tids, [lo, hi])
-            if i0 == i1:
-                continue
-            t = tids[i0:i1].astype(np.int64) - lo
-            w = arena.seg_view(g)[np.ix_(eh, t >> 5)]
-            bits[:, i0:i1] = (w >> (t & 31).astype(np.uint32)[None, :]
-                              ) & np.uint32(1)
-        return bits.sum(axis=1, dtype=np.int64), bits
 
     @staticmethod
     def _sweep_sparse(arena, r):
@@ -651,12 +617,15 @@ class SweepDispatcher:
         the latency a lone straggler pays when other workers are busy
         with non-sweep work.
 
+    Every sweep, on every backend and at every granularity, is a
+    request flushed by this thread: no caller runs the backend itself,
+    so ``flushes`` and ``sweep_requests`` count every join the engine
+    made, and ``batch_occupancy`` (requests per flush) shows whether
+    batching actually happened.
+
     Errors from the backend resolve every future in the flight batch,
     so task bodies re-raise through the scheduler's normal task-error
-    machinery. ``batch_occupancy`` (requests per flush) is the gauge
-    that shows whether batching actually happened — the granularity
-    benchmark asserts it stays above 1 so the dispatcher cannot
-    silently degrade to one-bucket launches.
+    machinery.
     """
 
     def __init__(self, arena: BitmapArena, backend: JoinBackend,
@@ -681,7 +650,7 @@ class SweepDispatcher:
         # descriptors. One reduction per flush, so the collective
         # amortizes exactly like the dispatcher amortizes launches.
         self.cluster = cluster
-        self.sweep_s = 0.0            # local backend busy time (s)
+        self.sweep_s = 0.0            # backend busy time (s)
         self._pending: List[SweepRequest] = []
         self._n_priority = 0          # priority requests in _pending
         self._cv = threading.Condition()
@@ -689,15 +658,10 @@ class SweepDispatcher:
         self.flushes = 0
         self.requests = 0
         self.query_requests = 0       # priority (serving) requests seen
-        # dispatcher-THREAD flushes only (excludes sweep_local /
-        # sweep_bits inline bursts, which bill themselves as flushes):
-        # the coalescing gauge the query-storm benchmark compares,
-        # since inline bursts never mix with anything by construction
-        self.queue_flushes = 0
-        self.queue_requests = 0
         # µs queued requests waited from submit to the start of their
         # flush, summed; and the per-kernel launch counters the
-        # backend adds to (only this dispatcher's thread writes either)
+        # backend adds to (only this dispatcher's thread writes these,
+        # ``sweep_s`` and, under ``_cv``, the flush gauges)
         self.queue_wait_us = 0
         self.work = dict.fromkeys(obs_schema.KERNEL_COUNTERS, 0)
         self._thread = threading.Thread(target=self._loop, daemon=True,
@@ -729,18 +693,6 @@ class SweepDispatcher:
             self._cv.notify_all()
         return req.future
 
-    def _make_requests(self, sweeps: Sequence[Tuple],
-                       segments: Optional[Sequence[int]],
-                       priority: bool = False
-                       ) -> List[SweepRequest]:
-        segs = tuple(segments) if segments is not None else None
-        return [SweepRequest(
-                    (tuple(int(h) for h in p) if isinstance(p, tuple)
-                     else int(p)),
-                    tuple(e), shard=self.shard, segments=segs,
-                    priority=priority)
-                for p, e in sweeps]
-
     def submit_many(self, sweeps: Sequence[Tuple],
                     segments: Optional[Sequence[int]] = None,
                     priority: bool = False) -> List[Future]:
@@ -752,7 +704,13 @@ class SweepDispatcher:
         ``priority=True`` marks the burst as query-class: it goes to
         the FRONT of the pending queue (order preserved within the
         burst) and shortens the straggler wait to ``query_flush_us``."""
-        reqs = self._make_requests(sweeps, segments, priority)
+        segs = tuple(segments) if segments is not None else None
+        reqs = [SweepRequest(
+                    (tuple(int(h) for h in p) if isinstance(p, tuple)
+                     else int(p)),
+                    tuple(e), shard=self.shard, segments=segs,
+                    priority=priority)
+                for p, e in sweeps]
         with self._cv:
             if self._stop:
                 raise RuntimeError("dispatcher is stopped")
@@ -764,46 +722,6 @@ class SweepDispatcher:
                 self._pending.extend(reqs)
             self._cv.notify_all()
         return [r.future for r in reqs]
-
-    def sweep_local(self, sweeps: Sequence[Tuple],
-                    segments: Optional[Sequence[int]] = None
-                    ) -> List[np.ndarray]:
-        """Execute a burst of ``(prefix, ext_handles)`` sweeps and
-        return counts arrays aligned with ``sweeps``.
-
-        When the backend is ``host_parallel`` (numpy) the burst runs
-        synchronously on the CALLING thread — its ufunc passes release
-        the GIL, so N worker threads executing their own bursts truly
-        parallelize instead of serializing behind the one dispatcher
-        thread (the delta path's wall-clock regression in a nutshell).
-        Kernel backends fall back to ``submit_many`` so only the
-        dispatcher thread ever touches JAX, and the burst still
-        coalesces into wide launches there. Either way the burst bills
-        the occupancy gauges as one flush of ``len(sweeps)`` requests.
-        """
-        if not sweeps:
-            return []
-        if not self.backend.host_parallel:
-            return [f.result()
-                    for f in self.submit_many(sweeps, segments=segments)]
-        reqs = self._make_requests(sweeps, segments)
-        with self._cv:
-            if self._stop:
-                raise RuntimeError("dispatcher is stopped")
-            self.flushes += 1
-            self.requests += len(reqs)
-        # inline burst: the flush span lands on the CALLING worker's
-        # lane (that is where the time went)
-        with region(self.tracer, "flush", cat="flush") as args:
-            t0 = time.perf_counter()
-            results = self.backend.sweep_many(self.arena, reqs)
-            with self._cv:
-                self.sweep_s += time.perf_counter() - t0
-            if self.cluster is not None:
-                results = self.cluster.reduce_flush(reqs, results)
-            if args is not None:
-                args.update(self._flush_args(reqs, inline=True))
-        return results
 
     def sweep(self, prefix_handle: int,
               ext_handles: Sequence[int],
@@ -819,53 +737,6 @@ class SweepDispatcher:
             return self.submit(prefix_handle, ext_handles,
                                segments=segments, desc=desc).result()
 
-    def sweep_bits(self, prefix_handle: int, ext_handles: Sequence[int],
-                   desc: Optional[Tuple[int, ...]] = None
-                   ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
-        """Depth-first class sweep: ``(counts, bits)`` where ``bits``
-        is the [E, S] payload∩ext matrix of the SAME gather the counts
-        came from (sparse prefixes on host-parallel backends; None
-        otherwise).
-
-        Host-parallel backends run inline on the CALLING thread — the
-        ``sweep_local`` rationale applied to class tasks: a class
-        sweep is one vectorized pass, so the enqueue → dispatcher
-        wakeup → future round-trip costs more than the sweep itself
-        (two context switches per class on a busy machine), and for a
-        sparse prefix returning the bit matrix lets the class task
-        carve children without re-gathering. Kernel backends keep the
-        batched queue (only the dispatcher thread touches JAX) and
-        return no bits. Billed as a 1-request flush so
-        ``flushes × occupancy == requests`` stays exact."""
-        if not self.backend.host_parallel:
-            return self.sweep(prefix_handle, ext_handles,
-                              desc=desc), None
-        req = self._make_requests(
-            [(prefix_handle, tuple(ext_handles))], None)[0]
-        req.desc = desc
-        with self._cv:
-            if self._stop:
-                raise RuntimeError("dispatcher is stopped")
-            self.flushes += 1
-            self.requests += 1
-        sparse = req.is_sparse(self.arena) and getattr(
-            self.backend, "sweep_sparse_bits", None) is not None
-        if sparse and self.arena.n_shards > 1:
-            self.arena.note_access(req.shard, (*req.prefix_handles,
-                                               *req.ext_handles))
-        with region(self.tracer, "sweep", cat="sweep") as args:
-            if args is not None:
-                args.update(ext=len(req.ext_handles), sparse=sparse)
-            if sparse:
-                return self.backend.sweep_sparse_bits(self.arena, req)
-            t0 = time.perf_counter()
-            counts = self.backend.sweep_many(self.arena, [req])[0]
-            with self._cv:
-                self.sweep_s += time.perf_counter() - t0
-            if self.cluster is not None:
-                counts = self.cluster.reduce_flush([req], [counts])[0]
-        return counts, None
-
     @property
     def batch_occupancy(self) -> float:
         return self.requests / self.flushes if self.flushes else 0.0
@@ -879,8 +750,6 @@ class SweepDispatcher:
             {"device": self.shard, "flushes": self.flushes,
              "sweep_requests": self.requests,
              "query_requests": self.query_requests,
-             "queue_flushes": self.queue_flushes,
-             "queue_requests": self.queue_requests,
              "queue_wait_us": self.queue_wait_us,
              **self.work,
              "sweep_s": self.sweep_s})
@@ -889,8 +758,8 @@ class SweepDispatcher:
         w = self.work
         return w["bitmap_join_launches"] + w["gather_intersect_launches"]
 
-    def _flush_args(self, batch: Sequence[SweepRequest], parts: int = 0,
-                    inline: bool = False) -> Dict[str, float]:
+    def _flush_args(self, batch: Sequence[SweepRequest],
+                    parts: int) -> Dict[str, float]:
         """Span payload for one flush: requests, rows, the dense/sparse
         representation split and ``parts``, the kernel launches it made
         (0 on a host backend). Only runs when a tracer is attached."""
@@ -901,7 +770,7 @@ class SweepDispatcher:
         return {"requests": len(batch), "rows": rows,
                 "sparse": sparse, "dense": len(batch) - sparse,
                 "queries": sum(1 for r in batch if r.priority),
-                "parts": parts, "inline": inline}
+                "parts": parts}
 
     # -------------------------------------------------------------- loop --
     def _loop(self):
@@ -927,10 +796,8 @@ class SweepDispatcher:
                 batch = self._pending[:self.max_batch]
                 del self._pending[:self.max_batch]
                 self._n_priority -= sum(1 for r in batch if r.priority)
-                self.flushes += 1       # gauges share the cv lock with
-                self.requests += len(batch)   # sweep_local's local bursts
-                self.queue_flushes += 1
-                self.queue_requests += len(batch)
+                self.flushes += 1
+                self.requests += len(batch)
                 t_start = time.perf_counter()
                 self.queue_wait_us += int(1e6 * sum(
                     t_start - r.t_submit for r in batch))
@@ -939,8 +806,7 @@ class SweepDispatcher:
                     launches = self._launches()
                     t0 = time.perf_counter()
                     results = self.backend.sweep_many(self.arena, batch)
-                    with self._cv:
-                        self.sweep_s += time.perf_counter() - t0
+                    self.sweep_s += time.perf_counter() - t0
                     if self.cluster is not None:
                         # the cross-host reduction tail of this flush
                         with region(tr, "net-flush", cat="net") as a:

@@ -210,28 +210,6 @@ def tids_to_bitmap(tids: np.ndarray, n_words_: int) -> np.ndarray:
     return out
 
 
-def gather_bits(tids: np.ndarray, ext_words: np.ndarray) -> np.ndarray:
-    """bit test of ``ext_words`` at each tid -> [len(tids)] bool.
-
-    The sparse sweep primitive: O(|tids|) gathered words regardless of
-    row width W — exactly what the Pallas ``gather_intersect_many``
-    kernel batches on device."""
-    if len(tids) == 0:
-        return np.zeros(0, bool)
-    t = np.asarray(tids, np.uint32)
-    return ((ext_words[t >> np.uint32(5)] >> (t & np.uint32(31)))
-            & np.uint32(1)).astype(bool)
-
-
-def gather_count(tids: np.ndarray, ext_words: np.ndarray) -> int:
-    """|tids ∩ ext| for one sparse row against one word-column."""
-    if len(tids) == 0:
-        return 0
-    t = np.asarray(tids, np.uint32)
-    return int((((ext_words[t >> np.uint32(5)] >> (t & np.uint32(31)))
-                 & np.uint32(1))).sum())
-
-
 def sorted_difference(a: np.ndarray, b: np.ndarray) -> np.ndarray:
     """a \\ b for sorted unique uint32 arrays (diffset reconstruction:
     tids(P) = tids(parent) \\ diffset). Binary-search based — ``np.isin``
@@ -834,8 +812,8 @@ class BitmapArena:
         """[len(handles), len(tids)] bool: bit test of each handle's
         DENSE row at each tid — the class task's batched child carve.
         One ``np.ix_`` gather per segment serves every row at once;
-        per-child :func:`gather_bits` calls pay ~10x numpy call
-        overhead for the same reads."""
+        one gather per child pays ~10x numpy call overhead for the
+        same reads."""
         out = np.zeros((len(handles), len(tids)), bool)
         if not len(tids) or not len(handles):
             return out

@@ -20,13 +20,13 @@ from repro.obs import schema as obs_schema
 
 
 def _dispatch_summary(per_device) -> str:
-    """The dispatchers' queue wait per queued request, and each
+    """The dispatchers' queue wait per sweep request, and each
     launched kernel's fill: logical over padded work, %."""
     c = obs_schema.merge_counters(per_device, obs_schema.DEVICE_COUNTERS)
     out = []
-    if c["queue_requests"]:
+    if c["sweep_requests"]:
         out.append(f"queue_wait="
-                   f"{c['queue_wait_us'] / c['queue_requests'] / 1e3:.3f}ms")
+                   f"{c['queue_wait_us'] / c['sweep_requests'] / 1e3:.3f}ms")
     for kernel, unit in (("bitmap_join", "words"),
                          ("gather_intersect", "probes")):
         padded = c[f"{kernel}_padded_{unit}"]
@@ -103,8 +103,8 @@ def main():
     ap.add_argument("--granularity", default="bucket",
                     choices=list(GRANULARITIES),
                     help="task grain: bucket (level-sync sweep), "
-                         "candidate (scalar joins), or depth-first "
-                         "(barrier-free class recursion)")
+                         "candidate (one-extension sweeps), or "
+                         "depth-first (barrier-free class recursion)")
     ap.add_argument("--representation", default="auto",
                     choices=list(REPRESENTATIONS),
                     help="row representation: bitmap (word-columns "

@@ -53,10 +53,9 @@ KERNEL_COUNTERS: Tuple[str, ...] = (
     "gather_intersect_padded_probes", "overlapped_launches",
 )
 # queue_wait_us: µs from submit to the start of the carrying flush,
-# summed over the dispatcher queue's requests
+# summed over the dispatcher's requests
 DEVICE_COUNTERS: Tuple[str, ...] = (
-    "flushes", "sweep_requests", "query_requests", "queue_flushes",
-    "queue_requests", "queue_wait_us",
+    "flushes", "sweep_requests", "query_requests", "queue_wait_us",
 ) + KERNEL_COUNTERS
 DEVICE_DERIVED: Tuple[str, ...] = ("batch_occupancy", "sweep_s")
 

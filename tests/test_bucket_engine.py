@@ -166,6 +166,10 @@ def test_engine_equivalence(datasets, name, policy, granularity):
                     granularity=granularity)
     assert got == ref, (name, policy, granularity)
     assert met.scheduler["tasks_run"] == met.scheduler["spawned"]
+    if granularity == "depth-first" and name != "chess":
+        # the default "auto" representation: dense mushroom stays
+        # all-bitmap, the sparse retail profile hands sparse rows down
+        assert (met.sparse_sweeps > 0) == (name == "retail"), name
 
 
 def test_bucket_rows_touched_below_candidate(datasets):
@@ -191,13 +195,12 @@ def test_backend_granularity_equivalence(datasets, granularity, backend):
     got, met = mine(bm, ms, policy="clustered", n_workers=2, max_k=3,
                     granularity=granularity, backend=backend)
     assert got == ref, (granularity, backend)
-    if granularity != "candidate":
-        # sweeps went through the dispatcher, and every request was
-        # answered by a flush
-        assert met.flushes > 0
-        assert round(met.flushes * met.batch_occupancy) == \
-            met.scheduler["sweeps_submitted"]
-    if backend == "pallas-interpret" and granularity != "candidate":
+    # sweeps went through the dispatcher, and every request was
+    # answered by a flush
+    assert met.flushes > 0
+    assert round(met.flushes * met.batch_occupancy) == \
+        met.scheduler["sweeps_submitted"]
+    if backend == "pallas-interpret":
         # device-resident arena: the h2d gauge saw the initial upload
         # plus incrementally synced prefix/handoff rows (at most ~2 per
         # sweep) — never a per-sweep re-upload of extension bitmaps

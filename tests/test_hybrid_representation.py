@@ -133,32 +133,6 @@ def test_one_flush_mixes_representations():
         disp.stop()
 
 
-def test_sweep_bits_returns_alignable_bit_matrix():
-    """host-parallel fast path: sweep_bits on a sparse prefix returns
-    (counts, bits) from ONE gather — bits[j, i] is ext j's membership
-    at payload position i, exactly gather_bits_rows' answer."""
-    arena, rows = _tid_arena()
-    pt = tidlist.bitmap_to_tids(rows[0] & rows[1])
-    ht = arena.push_tids(pt)
-    disp = jb.SweepDispatcher(arena, jb.get_backend("numpy"),
-                              n_clients=1, flush_us=1_000)
-    try:
-        counts, bits = disp.sweep_bits(ht, (2, 3, 4))
-        assert bits is not None and bits.shape == (3, len(pt))
-        np.testing.assert_array_equal(
-            counts, naive_counts(rows[0] & rows[1], rows[2:5]))
-        np.testing.assert_array_equal(bits.sum(axis=1), counts)
-        np.testing.assert_array_equal(
-            bits, arena.gather_bits_rows(pt, [2, 3, 4]))
-        # dense prefixes take the batched dense sweep: no bit matrix
-        dcounts, dbits = disp.sweep_bits(0, (2, 3, 4))
-        assert dbits is None
-        np.testing.assert_array_equal(
-            dcounts, naive_counts(rows[0], rows[2:5]))
-    finally:
-        disp.stop()
-
-
 def test_gather_bits_rows_matches_per_tid_bit_test():
     arena, rows = _tid_arena(n=5, w=4)
     tids = np.sort(RNG.choice(32 * 4, size=20, replace=False)
@@ -196,18 +170,26 @@ def test_mixed_representation_equivalence_matrix():
 
 
 def test_depth_first_sparse_subtrees_project_without_arena_rows():
-    """On the host backend, interior sparse classes are projections of
-    the root's bit matrix: sparse sweeps happen, arena sparse rows
-    don't (kernel backends still materialize arena rows — covered by
-    the pallas test below)."""
-    db = rand_db(600, n_items=12, lo=3, hi=9)
+    """The host backend mines sparse subtrees the way the kernels do:
+    sparse children are arena rows handed down (no projected bit
+    matrices), so numpy and pallas-interpret make the same
+    representation choices and push the same sparse rows."""
+    db = rand_db(300, n_items=12, lo=3, hi=9)
     bm, counts = pack_database(db, 12, return_counts=True)
-    res, met = mine(bm, 40, n_workers=3, max_k=5, backend="numpy",
-                    granularity="depth-first", representation="sparse",
-                    item_counts=counts)
-    assert met.sparse_sweeps > 0
-    assert met.sparse_rows == 0
-    assert res == mine_serial(bm, 40, max_k=5)
+    ref = mine_serial(bm, 20, max_k=5)
+    runs = {}
+    for backend in ("numpy", "pallas-interpret"):
+        res, met = mine(bm, 20, n_workers=3, max_k=5, backend=backend,
+                        granularity="depth-first",
+                        representation="sparse", item_counts=counts)
+        assert res == ref, backend
+        assert met.sparse_sweeps > 0
+        assert met.sparse_rows > 0           # arena rows, not masks
+        runs[backend] = met
+    np_met, pi_met = runs["numpy"], runs["pallas-interpret"]
+    assert np_met.sparse_rows == pi_met.sparse_rows
+    assert np_met.rep_picks == pi_met.rep_picks
+    assert np_met.rep_picks["diffset"] > 0
 
 
 def test_pallas_interpret_sparse_matches_serial():

@@ -313,3 +313,62 @@ def test_mine_with_unavailable_backend_raises_not_hangs():
     bm = RNG.integers(0, 2 ** 32, size=(6, 2), dtype=np.uint32)
     with pytest.raises(ValueError, match="not available"):
         mine(bm, 1, n_workers=2, max_k=3, backend="pallas-jit")
+
+
+# ------------------------------------------- every sweep is a flush
+def _basket_db(n_tx, n_items, seed):
+    rng = np.random.default_rng(seed)
+    return [sorted(rng.choice(n_items, size=int(rng.integers(3, 9)),
+                              replace=False).tolist())
+            for _ in range(n_tx)]
+
+
+@pytest.mark.parametrize("phase", ["mine", "refresh"])
+@pytest.mark.parametrize("granularity",
+                         ["bucket", "candidate", "depth-first", "auto"])
+def test_every_sweep_runs_on_a_dispatcher_thread(monkeypatch, granularity,
+                                                 phase):
+    """No engine path runs the backend itself: every ``sweep_many``
+    call of a mine, and of a refresh after an ingest, comes from a
+    dispatcher thread, at every granularity — on the host backend
+    exactly as on the kernels — and the requests it carries are the
+    sweeps the workers submitted."""
+    from repro.core.fpm import mine, mine_serial
+    from repro.core.streaming import StreamingMiner
+    calls = []
+    sweep_many = jb.NumpyBackend.sweep_many
+
+    def recording(self, arena, requests):
+        calls.append((threading.current_thread().name, len(requests)))
+        return sweep_many(self, arena, requests)
+
+    monkeypatch.setattr(jb.NumpyBackend, "sweep_many", recording)
+    db = _basket_db(400, 12, seed=11)
+    ms = 30
+    if phase == "mine":
+        bm, counts = tidlist.pack_database(db, 12, return_counts=True)
+        res, met = mine(bm, ms, n_workers=3, max_k=5, backend="numpy",
+                        granularity=granularity, item_counts=counts)
+        assert res == mine_serial(bm, ms, max_k=5)
+        assert sum(n for _, n in calls) == \
+            met.scheduler["sweeps_submitted"]
+    else:
+        sm = StreamingMiner(12, ms, initial_db=db[:340], n_workers=3,
+                            max_k=5, granularity=granularity,
+                            backend="numpy")
+        try:
+            sm.refresh()
+            sm.ingest(db[340:])
+            calls.clear()
+            rep = sm.refresh()
+            assert rep.swept_delta > 0        # a delta path ran
+            if granularity != "depth-first":
+                # the level drivers' dirty buckets go out as chunk
+                # bursts, enqueued at once, so they share flushes
+                assert max(n for _, n in calls) > 1
+            full = tidlist.pack_database(db, 12)
+            assert dict(sm.snapshot.supports) == mine_serial(full, ms,
+                                                             max_k=5)
+        finally:
+            sm.close()
+    assert {name for name, _ in calls} == {"sweep-dispatcher-0"}

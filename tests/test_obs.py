@@ -390,7 +390,8 @@ def test_traced_kernel_mine_splits_driver_and_flush(small_db,
         row["gather_intersect_padded_probes"]
     assert (row["gather_intersect_probes"] > 0) == \
         ("gather_intersect" in kernels)
-    assert row["queue_requests"] == row["sweep_requests"] > 0
+    assert row["flushes"] == len(flushes)
+    assert row["sweep_requests"] >= row["flushes"] > 0
     assert row["queue_wait_us"] > 0
     # the driver's top-level spans tile the call; a level-k span only
     # for a level that had candidates

@@ -49,10 +49,10 @@ def test_query_and_candidate_sweeps_share_one_flush():
         f_query = disp.submit(2, (3,), priority=True)  # query-class
         assert int(f_cand.result(timeout=10)[0]) == brute(db, (0, 1))
         assert int(f_query.result(timeout=10)[0]) == brute(db, (2, 3))
-        assert disp.queue_flushes == 1
-        assert disp.queue_requests == 2
+        assert disp.flushes == 1
+        assert disp.requests == 2
         assert disp.query_requests == 1
-        assert disp.queue_requests / disp.queue_flushes > 1
+        assert disp.batch_occupancy > 1
     finally:
         disp.stop()
 
