@@ -5,9 +5,10 @@
    candidate granularity (one single-extension sweep per task — the
    paper's §2 setting) and show the locality metrics that explain the difference
    (the Fig. 1 + Table 1 story).
-3. Re-mine at bucket granularity: one task per (k-1)-prefix, the prefix
-   intersection computed once, all extensions swept in one vectorized
-   call through the join backend — the same locality, made structural.
+3. Re-mine at bucket granularity: (k-1)-prefix buckets in chunk tasks,
+   each prefix intersection computed once, all its extensions swept in
+   one vectorized call through the join backend — the same locality,
+   made structural.
 4. Re-mine depth-first: barrier-free equivalence-class recursion where
    each task spawns its child classes and hands each child its already-
    intersected prefix bitmap — no barriers, no prefix recomputation,

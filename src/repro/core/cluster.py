@@ -537,7 +537,7 @@ def _drive(store: BitmapArena, runtime, min_support: int, max_k: int, *,
     return result, run.finalize(t0)
 
 
-_SUM_FIELDS = ("buckets", "cache_hits", "cache_misses",
+_SUM_FIELDS = ("buckets", "bursts", "cache_hits", "cache_misses",
                "cache_partial_hits", "rows_touched", "bytes_swept",
                "h2d_bytes", "flushes", "d2d_bytes", "migrations",
                "dense_sweeps", "sparse_sweeps", "sparse_bytes_swept",

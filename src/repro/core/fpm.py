@@ -9,11 +9,13 @@ mining algorithms for supercomputers"):
       cache iff they run back-to-back on the same worker — exactly the
       locality the clustered policy creates and the Cilk-style policy
       destroys. Its one-extension join is a dispatcher request.
-  granularity="bucket"       one task per (k-1)-prefix bucket (default).
-      The task resolves its prefix intersection ONCE (to an arena
-      handle) and enqueues one handle-based SweepRequest on the sweep
-      dispatcher, which coalesces many workers' buckets into batched
-      multi-prefix kernel launches (repro.core.join_backend).
+  granularity="bucket"       (k-1)-prefix buckets (default), spawned as
+      about four chunk tasks per worker of consecutive buckets. A chunk
+      task resolves each bucket's prefix intersection ONCE (to an arena
+      handle) and enqueues one handle-based SweepRequest per bucket as
+      one burst on the sweep dispatcher, which coalesces the workers'
+      bursts into batched multi-prefix kernel launches
+      (repro.core.join_backend).
       Level-synchronous: a driver barrier separates level k from k+1.
   granularity="depth-first"  barrier-free equivalence-class recursion.
       Each task owns one class (prefix P, sibling extensions E): it
@@ -57,6 +59,7 @@ from __future__ import annotations
 import collections
 import threading
 import time
+from concurrent.futures import wait as wait_futures
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
@@ -83,6 +86,7 @@ class MiningMetrics:
     levels: int = 0
     candidates: int = 0
     buckets: int = 0
+    bursts: int = 0              # chunk tasks: one submit_many each
     frequent: int = 0
     cache_hits: int = 0
     cache_misses: int = 0
@@ -730,8 +734,12 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
     from candidate generation to thresholding (candidate grain
     flattens it only to spawn), and a plain mine's collect pairs up
     only the frequent extensions, so no per-candidate tuple is built
-    on the driver's serial path. A candidate task is a bucket task
-    whose bucket holds one extension, so both grains sweep through the
+    on the driver's serial path. Bucket grain spawns a level's buckets
+    as chunk tasks of consecutive buckets, about four per worker; each
+    submits its sweeps as one burst, so the dispatcher has a backlog
+    to fill its flushes from (one blocking request per worker would
+    cap a flush at the worker count). A candidate task is a burst of
+    one one-extension bucket, so both grains sweep through the
     worker's device-affine dispatcher.
 
     With a ``delta`` plan the level's candidates split three ways:
@@ -739,13 +747,11 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
     known* (delta-swept over only the pending segments, support
     accumulated into ``delta.known``), and *fresh* (never swept —
     full sweep over the generation-boundary segments). Dirty buckets
-    are CHUNKED: one scheduler task carries ~hundreds of buckets and
-    submits them as a burst of tuple-prefix sweeps — the backend
-    AND-reduces each prefix's base rows over only the pending
+    are chunked the same way, each bucket a tuple-prefix sweep — the
+    backend AND-reduces each prefix's base rows over only the pending
     segments, so the delta path never builds a full-width prefix
-    intersection and its launches fill like the full path's. Tasks
-    carry ``delta.priority_of`` (when set) so the clustered policies
-    drain stale-hot prefixes first.
+    intersection. Fresh chunks carry ``delta.priority_of`` (when set)
+    so the clustered policies drain stale-hot prefixes first.
 
     ``granularity="auto"`` runs this driver with a per-bucket escape
     hatch: when the density model predicts a prefix's subtree is
@@ -806,29 +812,52 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
         st.bytes_swept += (rows_to_bytes(prows, n_w)
                            + rows_to_bytes(erows, _seg_w(segments)))
 
-    def sweep_task(bucket: Bucket, segments=None) -> np.ndarray:
-        """Bucket-granularity body: resolve the prefix handle once,
-        then one handle-based request on the worker's device-affine
-        dispatcher (which batches it with other workers' buckets on
-        the same shard). ``segments`` restricts a delta sweep to the
-        pending segments. Returns [E] counts."""
+    def _submit_burst(sweeps, segments) -> List[np.ndarray]:
+        """Enqueue ``sweeps`` with ONE ``submit_many`` on the worker's
+        device-affine dispatcher (which coalesces it with the other
+        workers' bursts into wide flushes) and wait for every count;
+        the worker lane's ``sweep`` span covers the wait. Every request
+        settles before this returns or raises, so the caller may
+        release the burst's handles afterwards."""
+        disp = dispatchers[sched.worker_device()]
+        with region(tr, "sweep", cat="sweep") as args:
+            if args is not None:
+                args.update(requests=len(sweeps),
+                            ext=sum(len(sw[1]) for sw in sweeps))
+            futures = disp.submit_many(sweeps, segments=segments)
+            wait_futures(futures)
+            return [f.result() for f in futures]
+
+    def burst_task(chunk: List[Bucket], segments=None
+                   ) -> List[np.ndarray]:
+        """Level-grain body: resolve each bucket's prefix handle once
+        through the worker's cache, then submit the chunk's sweeps as
+        one burst (a candidate task is a burst of one one-extension
+        bucket). ``segments`` restricts a delta sweep to the pending
+        segments. Returns one [E] count array per bucket."""
         cache = _thread_cache()
-        ph, prows = _prefix_handle(cache, bucket.prefix)
+        st = sched.worker_stats()
+        handles: List[int] = []
         try:
-            _account(prows, len(bucket.exts), segments)
-            st = sched.worker_stats()
-            st.sweeps_submitted += 1
-            if store.rep_of(ph) != tidlist.REP_BITMAP:
-                st.sparse_sweeps += 1
-                st.sparse_bytes_swept += (len(store.tids_of(ph)) * 4
-                                          * len(bucket.exts))
-            else:
-                st.dense_sweeps += 1
-            disp = dispatchers[sched.worker_device()]
-            return disp.sweep(ph, bucket.exts, segments=segments,
-                              desc=bucket.prefix)
+            sweeps = []
+            for b in chunk:
+                ph, prows = _prefix_handle(cache, b.prefix)
+                handles.append(ph)
+                _account(prows, len(b.exts), segments)
+                if store.rep_of(ph) != tidlist.REP_BITMAP:
+                    st.sparse_sweeps += 1
+                    st.sparse_bytes_swept += (len(store.tids_of(ph)) * 4
+                                              * len(b.exts))
+                else:
+                    st.dense_sweeps += 1
+                # desc: a cached prefix handle means nothing on a
+                # cluster peer, the prefix itemset does
+                sweeps.append((ph, b.exts, b.prefix))
+            st.sweeps_submitted += len(sweeps)
+            return _submit_burst(sweeps, segments)
         finally:
-            store.release(ph)
+            for ph in handles:
+                store.release(ph)
 
     def detach_task(bucket: Bucket, own_support: int,
                     psup: Tuple[int, ...]) -> None:
@@ -869,14 +898,30 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
                             attr=(b.key, b.prefix))
                 for b, ps, psup in detach)
             metrics.buckets += len(plan)
-            prio = delta.priority_of if delta is not None else None
-            tenant = delta.tenant if delta is not None else None
-            tasks = [sched.spawn(sweep_task, b, segments,
-                                 attr=(b.key, b.prefix),
-                                 priority=prio(b.prefix) if prio else 0.0,
-                                 tenant=tenant)
-                     for b in plan]
+            tasks = _spawn_chunks(burst_task, plan, segments,
+                                  delta.priority_of if delta else None)
         return plan, tasks
+
+    def _spawn_chunks(body, plan: List[Bucket], segments,
+                      prio=None) -> List:
+        """Spawn ``body(chunk, segments)`` over consecutive runs of
+        ``plan``, about four per worker, instead of one task per
+        bucket: a task's burst fills dispatcher flushes that one
+        blocking request per worker cannot, and a run of consecutive
+        buckets stays inside its prefix clusters, so the first
+        bucket's cluster places it. With ``prio`` (a refresh's
+        ``delta.priority_of``) a chunk carries its hottest bucket's
+        priority."""
+        tenant = delta.tenant if delta is not None else None
+        size = max(1, -(-len(plan) // (4 * sched.n)))
+        chunks = [plan[i:i + size] for i in range(0, len(plan), size)]
+        metrics.bursts += len(chunks)
+        return [sched.spawn(body, c, segments,
+                            attr=(c[0].key, c[0].prefix),
+                            priority=(max(prio(b.prefix) for b in c)
+                                      if prio else 0.0),
+                            tenant=tenant)
+                for c in chunks]
 
     def _spawn_candidates(plan: List[Bucket], segments):
         """One task per candidate: a sweep of its prefix's bucket cut
@@ -889,26 +934,23 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
             for e in b.exts:
                 c = b.prefix + (e,)
                 cands.append(c)
-                tasks.append(sched.spawn(sweep_task,
-                                         Bucket(b.key, b.prefix, (e,)),
+                tasks.append(sched.spawn(burst_task,
+                                         [Bucket(b.key, b.prefix, (e,))],
                                          segments, attr=(b.key, c),
                                          priority=priority,
                                          tenant=tenant))
         return cands, tasks
 
-    def delta_chunk_task(chunk: List[Bucket]
+    def delta_chunk_task(chunk: List[Bucket], segments
                          ) -> List[Tuple[Itemset, int]]:
         """Coalesced dirty-candidate burst: each bucket in the chunk
-        becomes ONE tuple-prefix sweep over the pending segments, and
-        the whole chunk is enqueued at once, so it coalesces into wide
-        dispatcher flushes. No prefix bitmap is ever built host-side."""
+        becomes ONE tuple-prefix sweep over the pending ``segments``
+        (a tuple prefix self-describes on a cluster peer). No prefix
+        bitmap is ever built host-side."""
         st = sched.worker_stats()
-        disp = dispatchers[sched.worker_device()]
-        futures = disp.submit_many(
-            [((b.prefix if len(b.prefix) > 1 else b.prefix[0]),
-              b.exts) for b in chunk],
-            segments=delta.segments)
-        counts_per_bucket = [f.result() for f in futures]
+        counts_per_bucket = _submit_burst(
+            [((b.prefix if len(b.prefix) > 1 else b.prefix[0]), b.exts)
+             for b in chunk], segments)
         st.sweeps_submitted += len(chunk)
         out: List[Tuple[Itemset, int]] = []
         rows = 0
@@ -917,25 +959,16 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
             out.extend((b.prefix + (e,), int(s))
                        for e, s in zip(b.exts, counts))
         st.rows_touched += rows
-        st.bytes_swept += rows_to_bytes(rows, _seg_w(delta.segments))
+        st.bytes_swept += rows_to_bytes(rows, _seg_w(segments))
         return out
 
     def _spawn_delta_chunks(plan: List[Bucket]) -> Callable[
             [], List[Tuple[Itemset, int]]]:
-        """Spawn a handful of chunk tasks (≈4 per worker) over the
-        already-classified dirty buckets instead of one task per
-        bucket — per-task scheduler and future overhead is what made
-        the delta path slower than the full path it was supposed to
-        beat."""
+        """Chunk tasks over the already-classified dirty buckets."""
         if not plan:
             return lambda: []
         metrics.buckets += len(plan)
-        n_chunks = max(1, 4 * sched.n)
-        size = max(1, -(-len(plan) // n_chunks))
-        tasks = [sched.spawn(delta_chunk_task, plan[i:i + size],
-                             attr=(plan[i].key, plan[i].prefix),
-                             tenant=delta.tenant)
-                 for i in range(0, len(plan), size)]
+        tasks = _spawn_chunks(delta_chunk_task, plan, delta.segments)
 
         def collect():
             _raise_task_errors(tasks)
@@ -964,13 +997,14 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
 
             def collect():
                 _raise_task_errors(tasks)
+                # chunks are consecutive runs of ``plan``, in order
+                counted = zip(plan, (c for t in tasks for c in t.result))
                 if delta is not None or cluster is not None:
                     return [(b.prefix + (e,), int(s))
-                            for b, t in zip(plan, tasks)
-                            for e, s in zip(b.exts, t.result)]
+                            for b, counts in counted
+                            for e, s in zip(b.exts, counts)]
                 out = []
-                for b, t in zip(plan, tasks):
-                    counts = t.result
+                for b, counts in counted:
                     for i in np.flatnonzero(
                             counts >= min_support).tolist():
                         out.append((b.prefix + (b.exts[i],),
@@ -982,7 +1016,7 @@ def _mine_levelwise(store, dispatchers, min_support, max_k, sched,
 
             def collect():
                 _raise_task_errors(tasks)
-                return [(c, int(t.result[0]))
+                return [(c, int(t.result[0][0]))
                         for c, t in zip(cands, tasks)]
         return collect
 

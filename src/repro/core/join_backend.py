@@ -606,13 +606,14 @@ class SweepDispatcher:
     batching, per-device occupancy gauges.
 
     Workers call :meth:`sweep` (or :meth:`submit` + ``future.result()``)
-    and block; the dedicated dispatcher thread gathers pending requests
-    and flushes a batch when either
+    and block, or enqueue a burst with :meth:`submit_many` and wait on
+    its futures; the dedicated dispatcher thread gathers pending
+    requests and flushes a batch when either
 
-      * ``min(max_batch, n_clients)`` requests are pending — since
-        ``sweep`` blocks its caller, pending requests count currently
-        blocked clients, so once every client is waiting no further
-        request can arrive and waiting longer is pure latency; or
+      * ``min(max_batch, n_clients)`` requests are pending — a blocked
+        client has at least one request pending, so once that many are
+        waiting, waiting longer is pure latency (a burst's backlog
+        fills flushes up to ``max_batch``); or
       * ``flush_us`` elapsed since the flush started forming — bounding
         the latency a lone straggler pays when other workers are busy
         with non-sweep work.
@@ -696,21 +697,25 @@ class SweepDispatcher:
     def submit_many(self, sweeps: Sequence[Tuple],
                     segments: Optional[Sequence[int]] = None,
                     priority: bool = False) -> List[Future]:
-        """Enqueue a burst of ``(prefix, ext_handles)`` sweeps under one
-        lock acquisition / one wakeup — the streaming delta path's
-        coalescing entry point (per-candidate ``submit`` calls would
+        """Enqueue a burst of ``(prefix, ext_handles)`` or ``(prefix,
+        ext_handles, desc)`` sweeps under one lock acquisition / one
+        wakeup — the level driver's and the streaming delta path's
+        coalescing entry point (per-request ``submit`` calls would
         trickle in and flush at occupancy ~1). ``prefix`` may be a
-        handle or a tuple of handles (AND-reduced in the backend).
-        ``priority=True`` marks the burst as query-class: it goes to
-        the FRONT of the pending queue (order preserved within the
-        burst) and shortens the straggler wait to ``query_flush_us``."""
+        handle or a tuple of handles (AND-reduced in the backend);
+        ``desc`` is the request's portable descriptor, as in
+        :meth:`submit`. ``priority=True`` marks the burst as
+        query-class: it goes to the FRONT of the pending queue (order
+        preserved within the burst) and shortens the straggler wait to
+        ``query_flush_us``."""
         segs = tuple(segments) if segments is not None else None
         reqs = [SweepRequest(
-                    (tuple(int(h) for h in p) if isinstance(p, tuple)
-                     else int(p)),
-                    tuple(e), shard=self.shard, segments=segs,
-                    priority=priority)
-                for p, e in sweeps]
+                    (tuple(int(h) for h in s[0]) if isinstance(s[0], tuple)
+                     else int(s[0])),
+                    tuple(s[1]), shard=self.shard, segments=segs,
+                    priority=priority,
+                    desc=s[2] if len(s) > 2 else None)
+                for s in sweeps]
         with self._cv:
             if self._stop:
                 raise RuntimeError("dispatcher is stopped")

@@ -6,13 +6,14 @@ import numpy as np
 import pytest
 from _hyp import given, settings, st
 
+from repro.core import join_backend as jb
 from repro.core import tidlist
 from repro.core.buckets import (bucket_rows_touched,
                                 candidate_rows_touched, rows_to_bytes)
 from repro.core.fpm import mine, mine_serial
 from repro.core.itemsets import (Bucket, brute_force_frequent,
                                  gen_buckets, gen_candidates, prefix_hash)
-from repro.core.tidlist import pack_database
+from repro.core.tidlist import BitmapArena, pack_database
 from repro.data.transactions import load
 
 POLICIES = ["cilk", "fifo", "clustered", "nn"]
@@ -273,6 +274,127 @@ def test_depth_first_single_frequent_item_spawns_nothing():
     got, met = mine(bm, 2, granularity="depth-first", n_workers=2)
     assert got == {(0,): 3}
     assert met.scheduler["spawned"] == 0
+
+
+# ---------------------------------------------------- burst submission
+N_WORKERS = 3
+
+
+@pytest.fixture(scope="module")
+def quest_small():
+    """1,000 transactions of the Quest T40I10 profile at 10% support:
+    seven levels, and a level 2 with far more buckets than four per
+    worker, so a level's chunk tasks each carry several buckets."""
+    db, p = load("t40i10", seed=0)
+    bm = pack_database(db[:1000], p.n_items)
+    ms = 100
+    n_freq = int((tidlist.popcount32(bm).sum(axis=1) >= ms).sum())
+    assert n_freq - 1 > 4 * N_WORKERS           # level 2's buckets
+    return bm, ms
+
+
+def _record_flushes(monkeypatch, fault=None):
+    """Record each numpy flush's request count; a flush for which
+    ``fault(arena, requests)`` is true raises instead."""
+    calls = []
+    sweep_many = jb.NumpyBackend.sweep_many
+
+    def recording(self, arena, requests):
+        calls.append(len(requests))
+        if fault is not None and fault(arena, requests):
+            raise RuntimeError("injected flush fault")
+        return sweep_many(self, arena, requests)
+
+    monkeypatch.setattr(jb.NumpyBackend, "sweep_many", recording)
+    return calls
+
+
+def _record_arenas(monkeypatch):
+    """The arena each ``mine`` call builds, so a test can read its
+    refcounts after the call returned or raised."""
+    arenas = []
+    make = BitmapArena.from_bitmaps.__func__
+
+    def recording(cls, *args, **kwargs):
+        arenas.append(make(cls, *args, **kwargs))
+        return arenas[-1]
+
+    monkeypatch.setattr(BitmapArena, "from_bitmaps", classmethod(recording))
+    return arenas
+
+
+def _assert_only_base_rows_live(arena):
+    assert arena.live_extra == 0
+    assert all(arena.refcount(h) == 0
+               for h in range(arena.n_base, arena.n_rows))
+
+
+def test_level_buckets_go_out_in_bursts(quest_small, monkeypatch):
+    """Bucket grain spawns a level as chunk tasks, each one burst:
+    exact against the serial reference, one task per burst, fewer
+    bursts than buckets, and flushes wider than one request per
+    worker could make them."""
+    bm, ms = quest_small
+    calls = _record_flushes(monkeypatch)
+    got, met = mine(bm, ms, n_workers=N_WORKERS, granularity="bucket",
+                    backend="numpy")
+    assert got == mine_serial(bm, ms)
+    assert met.levels >= 3                      # cached prefixes swept
+    assert met.bursts == met.scheduler["tasks_run"]
+    assert 0 < met.bursts < met.buckets
+    assert len(calls) == met.flushes
+    assert sum(calls) == met.scheduler["sweeps_submitted"] == met.buckets
+    assert max(calls) > N_WORKERS
+
+
+@pytest.mark.parametrize("fault", [False, True],
+                         ids=["clean", "backend-raises-mid-burst"])
+def test_bursts_release_every_prefix_handle(quest_small, monkeypatch,
+                                            fault):
+    """A burst releases every prefix handle it resolved once all its
+    requests settled, also when a flush of the burst raised (here the
+    first flush carrying a cached prefix row, at level 3): after the
+    mine only the pinned base rows are live."""
+    bm, ms = quest_small
+    fired = []
+
+    def first_cached_prefix_flush(arena, requests):
+        if fired or not any(not isinstance(r.prefix_handle, tuple)
+                            and r.prefix_handle >= arena.n_base
+                            for r in requests):
+            return False
+        fired.append(len(requests))
+        return True
+
+    _record_flushes(monkeypatch, first_cached_prefix_flush if fault
+                    else None)
+    arenas = _record_arenas(monkeypatch)
+    if fault:
+        with pytest.raises(RuntimeError, match="injected flush fault"):
+            mine(bm, ms, n_workers=N_WORKERS, granularity="bucket",
+                 backend="numpy")
+        assert fired
+    else:
+        got, met = mine(bm, ms, n_workers=N_WORKERS,
+                        granularity="bucket", backend="numpy")
+        assert got == mine_serial(bm, ms)
+        assert met.cache_misses > 0             # prefix rows were built
+    assert len(arenas) == 1
+    _assert_only_base_rows_live(arenas[0])
+
+
+def test_cluster_bursts_carry_prefix_descriptors(quest_small):
+    """Two hosts re-evaluate every flush from its requests' portable
+    descriptors; a burst's cached prefix handles mean nothing on the
+    peer, so the result is exact only if ``submit_many`` carries each
+    request's prefix itemset."""
+    bm, ms = quest_small
+    got, met = mine(bm, ms, hosts=2, n_workers=N_WORKERS,
+                    granularity="bucket", backend="numpy")
+    assert got == mine_serial(bm, ms)
+    assert met.n_hosts == 2 and met.net_bytes > 0
+    assert met.levels >= 3 and met.cache_misses > 0
+    assert 0 < met.bursts < met.buckets
 
 
 # ------------------------------------------------------ property tests

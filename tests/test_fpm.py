@@ -55,17 +55,17 @@ def test_parallel_matches_serial(small_db, policy):
 
 @pytest.mark.parametrize("policy", ["cilk", "fifo", "clustered"])
 def test_bucket_granularity_matches_serial(small_db, policy):
-    """Default granularity: one task per prefix bucket, counts by
-    vectorized sweep — identical supports, ~candidates/avg-bucket-size
-    tasks."""
+    """Default granularity: prefix buckets spawned as burst tasks of
+    consecutive buckets, counts by vectorized sweep — identical
+    supports, one task per burst and fewer bursts than buckets."""
     db, p = small_db
     bm = pack_database(db, p.n_dense_items)
     ms = int(0.3 * len(db))
     ref = mine_serial(bm, ms, max_k=4)
     got, metrics = mine(bm, ms, policy=policy, n_workers=4, max_k=4)
     assert got == ref
-    assert metrics.scheduler["tasks_run"] == metrics.buckets
-    assert metrics.buckets < metrics.candidates
+    assert metrics.scheduler["tasks_run"] == metrics.bursts
+    assert 0 < metrics.bursts < metrics.buckets < metrics.candidates
     assert metrics.rows_touched > 0
     assert metrics.bytes_swept > 0
 

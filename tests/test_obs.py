@@ -304,7 +304,9 @@ def test_traced_mine_matches_untraced_and_covers_workers(small_db):
     Perfetto-loadable trace with one lane per worker carrying task +
     flush/sweep + steal spans, well-formed nesting, and per-worker
     time-in-state that tiles the worker's active extent to within
-    5%."""
+    5%. Each task is a burst: one worker-lane ``sweep`` span covers
+    its wait, and the spans' ``requests`` add up to the dispatcher's
+    sweep requests."""
     db, p = small_db
     bm, counts = pack_database(db, p.n_dense_items, return_counts=True)
     ms = int(0.3 * len(db))
@@ -325,6 +327,12 @@ def test_traced_mine_matches_untraced_and_covers_workers(small_db):
     assert check_nesting(tr.events()) == []
     per_worker = {e.lane for e in spans if e.cat == "task"}
     assert per_worker >= set(workers)              # every worker ran tasks
+    sweeps = [e for e in spans if e.name == "sweep"]
+    assert {e.lane for e in sweeps} <= set(workers)
+    assert len(sweeps) == met.bursts == met.scheduler["tasks_run"]
+    assert sum(e.args["requests"] for e in sweeps) == \
+        sum(row["sweep_requests"] for row in met.per_device)
+    assert max(e.args["requests"] for e in sweeps) > 1
     for key, row in time_in_state(tr).items():
         if not row["lane"].startswith("worker-"):
             continue
@@ -342,8 +350,8 @@ def test_traced_mine_matches_untraced_and_covers_workers(small_db):
 DRIVER_SPANS = {"mine.arena", "mine.level1", "mine.start", "mine.close",
                 "mine.finalize", "level.candidates", "level.plan",
                 "level.spawn", "level.barrier", "level.collect"}
-DISPATCHER_SPANS = {"dispatch.idle", "dispatch.form", "flush",
-                    "flush.prepare", "flush.launch", "flush.wait"}
+DISPATCHER_SPANS = {"dispatch.idle", "flush", "flush.prepare",
+                    "flush.launch", "flush.wait"}
 
 
 @pytest.mark.parametrize("representation,support",
@@ -355,7 +363,10 @@ def test_traced_kernel_mine_splits_driver_and_flush(small_db,
     steps, every flush holds one prepare/launch/wait triple per kernel
     launch, and each kernel's logical work is at most its padded
     work (the sparse case reaches level 3, whose cached prefixes are
-    tid-lists swept by gather_intersect)."""
+    tid-lists swept by gather_intersect). A flush forms either after
+    a ``dispatch.form`` straggler wait or at once from a burst's
+    backlog, so there is at most one wait per flush, and the flushes'
+    ``requests`` add up to the dispatcher's sweep requests."""
     db, p = small_db
     bm, counts = pack_database(db, p.n_dense_items, return_counts=True)
     ms = int(support * len(db))
@@ -392,6 +403,10 @@ def test_traced_kernel_mine_splits_driver_and_flush(small_db,
         ("gather_intersect" in kernels)
     assert row["flushes"] == len(flushes)
     assert row["sweep_requests"] >= row["flushes"] > 0
+    assert sum(e.args["requests"] for e in flushes) == row["sweep_requests"]
+    forms = [e for e in spans if e.name == "dispatch.form"]
+    assert {e.lane for e in forms} <= {"dispatcher-0"}
+    assert len(forms) <= len(flushes)
     assert row["queue_wait_us"] > 0
     # the driver's top-level spans tile the call; a level-k span only
     # for a level that had candidates
